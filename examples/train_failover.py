@@ -4,11 +4,10 @@ bit-exact resume from the cache replica (paper Fig 7, as training).
     PYTHONPATH=src python examples/train_failover.py
 """
 import sys
-import tempfile
 
 from repro.launch import train
 
 if __name__ == "__main__":
     train.main(["--arch", "gemma3-1b-reduced", "--steps", "14",
-                "--ckpt-every", "4", "--inject-failure", "10",
-                "--workdir", tempfile.mkdtemp()] + sys.argv[1:])
+                "--ckpt-every", "4", "--inject-failure", "10"]
+               + sys.argv[1:])

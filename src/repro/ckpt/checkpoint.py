@@ -1,8 +1,9 @@
 """AssiseCheckpointer: training state through the CC-NVM layer.
 
 Each worker owns a LibState (colocated persistent cache + chain
-replication). A checkpoint is a set of *per-tensor-shard* PUTs — the
-operation granularity the paper advocates — followed by a manifest PUT
+replication). A checkpoint is a set of per-leaf PUTs — the operation
+granularity the paper advocates; each leaf is copied to the host whole,
+so a sharded leaf is gathered — followed by a manifest PUT
 and an fsync (pessimistic: survives the worker AND its node) or dsync
 (optimistic: coalesced; bounded at-risk window). In full mode prefix
 semantics make the manifest write the atomic commit point: a restore
@@ -12,8 +13,10 @@ Delta mode logs only changed blocks vs. the previous step (redundant-
 write elimination for sparse-update tensors: embeddings, cold experts).
 Each leaf lives at a **stable key** and a step's changes are emitted as
 ``LibState.write`` byte-range writes straight from the changed-block
-bitmap — the Pallas ``delta_mask`` kernel output when available (indices
-× block → offsets), the host scan otherwise. Only the changed ranges
+bitmap (indices × block → offsets). On a TPU the compiled Pallas
+``delta_mask`` kernel scans each leaf's tile-aligned prefix; the host
+scan covers the tail, and the whole leaf on any other platform. A
+kernel error propagates: there is no silent fallback. Only the changed ranges
 are logged, replicated, and digested; the tradeoff vs per-step blobs is
 that in-place deltas make only the *latest* step restorable (older
 manifests are kept solely as the commit-point protocol's history), and
@@ -29,66 +32,61 @@ from __future__ import annotations
 
 import io
 import json
+import sys
 import threading
 import time
 import zlib
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from functools import partial
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from repro.ckpt.delta import changed_blocks, changed_extents
 from repro.core.store import LibState
 
-_KERNEL_BPT = 8
-_kernel_ok = True  # flips off after the first failed Pallas attempt
-FORCE_KERNEL = False  # tests: exercise the kernel path on CPU (interpret)
+_KERNEL_BPT = 8  # blocks per delta_mask grid step
 
 
-def _kernel_wanted() -> bool:
-    """The Pallas scan is the compiled on-device path; in interpret mode
-    (CPU container) it is correctness-only and far slower than the host
-    scan, so it is used on TPU or when explicitly forced."""
-    if FORCE_KERNEL:
-        return True
-    if not _kernel_ok:
-        return False
-    import sys
-    if "jax" not in sys.modules:
-        return False  # a TPU training process has jax loaded already;
-        # don't pay the import just to ask the backend
-    try:
-        return sys.modules["jax"].default_backend() == "tpu"
-    except Exception:
-        return False
+def kernel_changed_blocks(new: bytes, old: bytes, block: int, *,
+                          interpret: bool) -> Tuple[List[int], int]:
+    """Changed blocks of the tile-aligned prefix of two equal-length
+    encodings, found by the Pallas ``delta_mask`` kernel. The bytes go
+    to the device as 32-bit words viewed on the host (the kernel's
+    interface). Returns (block indices, prefix bytes scanned)."""
+    import jax.numpy as jnp
 
-
-def _changed_block_idxs(new: bytes, old: bytes, block: int) -> List[int]:
-    """Changed-block bitmap: Pallas ``delta_mask`` on the tile-aligned
-    prefix (on-device scan before D2H in a real deployment), host scan
-    for the tail / when the kernel or backend is unavailable."""
-    global _kernel_ok
+    from repro.kernels.delta_encode import delta_mask
     tile = block * _KERNEL_BPT
-    aligned = (len(new) // tile) * tile
-    idxs: List[int] = []
-    if aligned and _kernel_wanted():
-        try:
-            import jax.numpy as jnp
+    aligned = len(new) // tile * tile
+    if not aligned:
+        return [], 0
+    nw = np.frombuffer(new, np.uint32, count=aligned // 4)
+    ow = np.frombuffer(old, np.uint32, count=aligned // 4)
+    mask = delta_mask(jnp.asarray(nw), jnp.asarray(ow), block=block,
+                      bpt=_KERNEL_BPT, interpret=interpret)
+    return np.flatnonzero(np.asarray(mask)).tolist(), aligned
 
-            from repro.kernels.ops import delta_mask
-            nv = np.frombuffer(new[:aligned], np.uint8)
-            ov = np.frombuffer(old[:aligned], np.uint8)
-            mask = np.asarray(delta_mask(jnp.asarray(nv), jnp.asarray(ov),
-                                         block=block, bpt=_KERNEL_BPT))
-            idxs = np.nonzero(mask)[0].tolist()
-        except Exception:  # missing/broken accelerator stack: host path
-            _kernel_ok = False
-            aligned = 0
-    else:
-        aligned = 0
-    first_tail = aligned // block
+
+def changed_block_idxs(new: bytes, old: bytes, block: int, scan=None
+                       ) -> Tuple[List[int], int]:
+    """Changed-block indices of ``new`` against ``old``: ``scan`` (the
+    kernel) on the tile-aligned prefix when given, the host scan on the
+    rest. Returns (indices, bytes the kernel scanned)."""
+    idxs, aligned = scan(new, old, block) if scan is not None else ([], 0)
     tail = changed_blocks(new[aligned:], old[aligned:], block)
-    return idxs + [i + first_tail for i in tail]
+    return idxs + [i + aligned // block for i in tail], aligned
+
+
+def _device_scan():
+    """The scan this process runs on each leaf's tile-aligned prefix:
+    the compiled kernel on a TPU, None (host scan only) elsewhere. A TPU
+    training process has JAX loaded already; without JAX there is no
+    device to scan on, so the import is not paid just to ask."""
+    jax = sys.modules.get("jax")
+    if jax is None or jax.default_backend() != "tpu":
+        return None
+    return partial(kernel_changed_blocks, interpret=False)
 
 
 @dataclass(frozen=True)
@@ -120,7 +118,7 @@ def _flatten(tree: Any, prefix: str = "") -> Dict[str, np.ndarray]:
         for i, v in enumerate(tree):
             out.update(_flatten(v, f"{prefix}/{i}"))
     else:
-        out[prefix] = np.asarray(tree)
+        out[prefix] = tree
     return out
 
 
@@ -132,8 +130,21 @@ class AssiseCheckpointer:
         self._prev: Dict[str, bytes] = {}  # previous encoded leaves
         self._saved_steps = []
         self._pending: Optional[threading.Thread] = None
+        # changed-block scan of each leaf's tile-aligned prefix, chosen
+        # once by platform; the rest of every leaf is host-scanned
+        self._scan = _device_scan()
+        # *_s: seconds per save phase, summed over saves; kernel_bytes /
+        # host_scan_bytes: bytes whose changed blocks each scan found
         self.stats = {"bytes_full": 0, "bytes_logged": 0, "saves": 0,
-                      "commit_s": 0.0}
+                      "commit_s": 0.0, "d2h_s": 0.0, "encode_s": 0.0,
+                      "scan_s": 0.0, "put_s": 0.0, "fsync_s": 0.0,
+                      "kernel_bytes": 0, "host_scan_bytes": 0}
+
+    @property
+    def last_saved(self) -> Dict[str, bytes]:
+        """Encoded leaves of the last save: what a restore of that step
+        must reproduce byte for byte."""
+        return self._prev
 
     def _leaf_key(self, step: int, name: str) -> str:
         if self.cfg.delta:  # stable key: steps patch it in place
@@ -151,27 +162,40 @@ class AssiseCheckpointer:
                     "format": "range" if self.cfg.delta else "full",
                     "leaf_crc": {}}
         new_prev = {}
+        st = self.stats
         for name, arr in leaves.items():
-            raw = _encode_leaf(np.asarray(arr))
+            t = time.perf_counter()
+            host = np.asarray(arr)  # device leaves copy out one at a time
+            st["d2h_s"] += time.perf_counter() - t
+            t = time.perf_counter()
+            raw = _encode_leaf(host)
+            del host
             manifest["leaf_crc"][name] = zlib.crc32(raw) & 0xFFFFFFFF
-            self.stats["bytes_full"] += len(raw)
+            st["encode_s"] += time.perf_counter() - t
+            st["bytes_full"] += len(raw)
             key = self._leaf_key(step, name)
             old = self._prev.get(name) if self.cfg.delta else None
+            extents = None
             if old is not None and len(old) == len(raw):
-                idxs = _changed_block_idxs(raw, old, self.cfg.delta_block)
+                t = time.perf_counter()
+                idxs, aligned = changed_block_idxs(
+                    raw, old, self.cfg.delta_block, self._scan)
+                st["kernel_bytes"] += aligned
+                st["host_scan_bytes"] += len(raw) - aligned
                 extents = changed_extents(raw, old, self.cfg.delta_block,
                                           idxs=idxs)
-                if sum(ln for _, ln in extents) < len(raw):
-                    for off, ln in extents:  # range writes: the paper's
-                        # op-granularity — only changed bytes hit the log
-                        self.store.write(key, raw[off:off + ln], off)
-                        self.stats["bytes_logged"] += ln
-                else:
-                    self.store.put(key, raw)
-                    self.stats["bytes_logged"] += len(raw)
+                st["scan_s"] += time.perf_counter() - t
+            t = time.perf_counter()
+            if extents is not None and \
+                    sum(ln for _, ln in extents) < len(raw):
+                for off, ln in extents:  # range writes: the paper's
+                    # op-granularity — only changed bytes hit the log
+                    self.store.write(key, raw[off:off + ln], off)
+                    st["bytes_logged"] += ln
             else:
                 self.store.put(key, raw)
-                self.stats["bytes_logged"] += len(raw)
+                st["bytes_logged"] += len(raw)
+            st["put_s"] += time.perf_counter() - t
             new_prev[name] = raw
         # manifest last: the atomic commit point under prefix semantics
         self.store.put(f"{self.cfg.prefix}/MANIFEST.{step}",
@@ -180,10 +204,12 @@ class AssiseCheckpointer:
                        str(step).encode())
 
         def commit():
+            t = time.perf_counter()
             if self.cfg.mode == "pessimistic":
                 self.store.fsync()
             else:
                 self.store.dsync()
+            st["fsync_s"] += time.perf_counter() - t
 
         if self.cfg.async_commit:
             self._pending = threading.Thread(target=commit)

@@ -59,8 +59,11 @@ class TokenPipeline:
     # -- pure batch function ---------------------------------------------------
     def batch_at(self, step: int) -> dict:
         st = self.state
+        # step and shard sit in the counter's high words: Philox counts
+        # up from the low word as it draws, so a low-word step would make
+        # batch `step + 1` the tail of batch `step`, shifted a few tokens
         rng = np.random.Generator(np.random.Philox(
-            key=st.seed, counter=[step, st.shard, 0, 0]))
+            key=st.seed, counter=[0, 0, st.shard, step]))
         tokens = rng.integers(0, self.vocab,
                               (self.local_batch, self.seq + 1),
                               dtype=np.int32)

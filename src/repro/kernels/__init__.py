@@ -1,3 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the checkpoint path: ``delta_encode.delta_mask``
+(the changed-block scan) and its jnp oracle in ``ref.py``."""

@@ -1,54 +1,8 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness ground truth).
-
-Each function is the mathematical definition, written for clarity not
-speed; tests sweep shapes/dtypes and assert_allclose kernels against
-these.
-"""
+"""Pure-jnp oracle for the Pallas kernel (the correctness ground truth):
+the mathematical definition, written for clarity not speed."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-
-
-def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        window=None, scale=None):
-    """q,k,v: (B, H, S, D) -> (B, H, S, D). Full-matrix softmax attention."""
-    b, h, s, d = q.shape
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    logits = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    qi = jnp.arange(s)[:, None]
-    ki = jnp.arange(s)[None, :]
-    mask = jnp.ones((s, s), bool)
-    if causal:
-        mask &= ki <= qi
-    if window is not None:
-        mask &= (qi - ki) < window
-    logits = jnp.where(mask, logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
-    return out.astype(q.dtype)
-
-
-def ssm_scan_ref(decay, u, c, state0):
-    """Selective-scan oracle (Mamba inner recurrence), sequential.
-
-    decay: (B, S, D, N) in (0,1]; u: (B, S, D, N); c: (B, S, N);
-    state0: (B, D, N).  Returns (y: (B, S, D), final_state: (B, D, N)).
-      s_t = decay_t * s_{t-1} + u_t ;  y_t = sum_n s_t[:, :, n] * c_t[n]
-    """
-    def step(s, xs):
-        d_t, u_t, c_t = xs
-        s = d_t * s + u_t
-        y = jnp.einsum("bdn,bn->bd", s, c_t)
-        return s, y
-
-    xs = (decay.transpose(1, 0, 2, 3), u.transpose(1, 0, 2, 3),
-          c.transpose(1, 0, 2))
-    state, ys = jax.lax.scan(step, state0.astype(jnp.float32),
-                             jax.tree.map(lambda t: t.astype(jnp.float32),
-                                          xs))
-    return ys.transpose(1, 0, 2), state
 
 
 def delta_encode_ref(new, old, block: int):

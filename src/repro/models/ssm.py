@@ -3,14 +3,14 @@
 Both use *chunked* scans for train/prefill: a sequential outer scan over
 sequence chunks carrying O(1) recurrent state, with parallel intra-chunk
 work — the TPU-native adaptation of the CUDA selective-scan kernels (see
-DESIGN.md). kernels/ssm_scan.py is the Pallas version of the inner chunk.
-Decode is a single-step state update.
+DESIGN.md), in plain jnp. Decode is a single-step state update.
 
 Numerics: decays and states are f32; all pairwise decay terms are
 exp(negative) — no overflow by construction.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
@@ -310,6 +310,10 @@ def rwkv_time_mix(params: dict, x: Array, spec: RWKVSpec, *, chunk: int = 64,
         state0 = cache["wkv"] if cache is not None else \
             jnp.zeros((b, h, dh, dh), jnp.float32)
 
+        # rematerialized per chunk: the backward pass keeps only each
+        # chunk's inputs and carried state, not its (B,L,L,H,dk) pairwise
+        # decays — at published widths those would take tens of GB
+        @partial(jax.checkpoint, prevent_cse=False)
         def body(st, xs_):
             rc, kc, vc, lwc = xs_
             y, st_new = _rwkv_chunk(rc, kc, vc, lwc, u, st)

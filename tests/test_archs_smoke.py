@@ -62,6 +62,7 @@ def test_param_counts_match_published():
         "stablelm-12b": 12.1, "minicpm3-4b": 4.1, "gemma3-1b": 1.0,
         "phi3.5-moe-42b-a6.6b": 41.9, "deepseek-moe-16b": 16.4,
         "rwkv6-1.6b": 1.6, "qwen2-vl-2b": 1.5, "musicgen-large": 2.4,
+        "rwkv6-1.6b-2l-v16k": 0.178,  # configs/rwkv6_1_6b_2l_v16k.py
     }
     for arch, bn in expect.items():
         n = count_params(get_config(arch)) / 1e9
@@ -73,4 +74,5 @@ def test_long_500k_applicability_flags():
     ls = SHAPES["long_500k"]
     runs = {a for a in ARCH_IDS
             if shape_applicable(get_config(a), ls)}
-    assert runs == {"jamba-1.5-large-398b", "rwkv6-1.6b", "gemma3-1b"}
+    assert runs == {"jamba-1.5-large-398b", "rwkv6-1.6b",
+                    "rwkv6-1.6b-2l-v16k", "gemma3-1b"}

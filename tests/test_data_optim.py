@@ -28,6 +28,13 @@ def test_pipeline_determinism():
         "tokens"])
 
 
+def test_pipeline_steps_do_not_overlap():
+    p = _pipe(seed=7, vocab_size=65_536)
+    b0, b1 = (p.batch_at(i)["tokens"].ravel() for i in (0, 1))
+    n = len(b0)
+    assert not any(np.array_equal(b0[k:], b1[:n - k]) for k in range(n - 8))
+
+
 def test_pipeline_snapshot_restore():
     p = _pipe(seed=1)
     p.next()

@@ -515,22 +515,25 @@ def test_segstore_get_range_base_fast_path(tmp_path):
     s.close()
 
 
-def test_delta_kernel_path_matches_host_scan():
-    """Forcing the Pallas delta_mask path (interpret mode on CPU) must
-    produce the same changed-block set as the host scan."""
+def test_delta_kernel_path_matches_host_scan(tmp_cluster):
+    """The checkpointer's device path (the Pallas delta_mask kernel, in
+    interpret mode on CPU) must produce the same changed-block set as
+    the host scan; off a TPU the checkpointer picks the host scan."""
+    from functools import partial
+
+    from repro.ckpt import AssiseCheckpointer
     from repro.ckpt import checkpoint as C
     rng = np.random.default_rng(1)
-    old = rng.integers(0, 256, 64 * 256 + 100, dtype=np.uint8).tobytes()
+    old = rng.integers(0, 256, 64 * 512 + 100, dtype=np.uint8).tobytes()
     new = bytearray(old)
     new[70] ^= 0xFF        # inside the tile-aligned prefix
-    new[64 * 256 + 50] ^= 0xFF  # inside the host-scanned tail
-    host = C._changed_block_idxs(bytes(new), old, 256)
-    C.FORCE_KERNEL = True
-    try:
-        kern = C._changed_block_idxs(bytes(new), old, 256)
-    finally:
-        C.FORCE_KERNEL = False
-    assert kern == host == [0, 64]
+    new[64 * 512 + 50] ^= 0xFF  # inside the host-scanned tail
+    host = C.changed_block_idxs(bytes(new), old, 512)
+    kern = C.changed_block_idxs(bytes(new), old, 512, partial(
+        C.kernel_changed_blocks, interpret=True))
+    assert host == ([0, 64], 0)
+    assert kern == ([0, 64], 64 * 512)
+    assert AssiseCheckpointer(tmp_cluster.open_process("kp"))._scan is None
 
 
 def test_changed_extents_merges_runs():

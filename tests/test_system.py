@@ -13,11 +13,11 @@ def test_train_failover_bitexact(tmp_path):
     losses = T.main(["--arch", "gemma3-1b-reduced", "--steps", "10",
                      "--batch", "2", "--seq", "32", "--ckpt-every", "3",
                      "--inject-failure", "7",
-                     "--workdir", str(tmp_path / "w1")])
+                     "--workdir", str(tmp_path / "w1")]).losses
     # reference run without failure, same seeds
     ref = T.main(["--arch", "gemma3-1b-reduced", "--steps", "10",
                   "--batch", "2", "--seq", "32", "--ckpt-every", "3",
-                  "--workdir", str(tmp_path / "w2")])
+                  "--workdir", str(tmp_path / "w2")]).losses
     # the post-failover tail replays the exact same loss trajectory
     np.testing.assert_allclose(losses[-3:], ref[-3:], rtol=1e-6)
 
