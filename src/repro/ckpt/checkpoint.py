@@ -133,10 +133,11 @@ class AssiseCheckpointer:
         # changed-block scan of each leaf's tile-aligned prefix, chosen
         # once by platform; the rest of every leaf is host-scanned
         self._scan = _device_scan()
-        # *_s: seconds per save phase, summed over saves; kernel_bytes /
-        # host_scan_bytes: bytes whose changed blocks each scan found
+        # *_s: seconds per save phase, summed over saves (d2h, encode
+        # and scan from their spans); kernel_bytes / host_scan_bytes:
+        # bytes whose changed blocks each scan found
         self.stats = {"bytes_full": 0, "bytes_logged": 0, "saves": 0,
-                      "commit_s": 0.0, "d2h_s": 0.0, "encode_s": 0.0,
+                      "d2h_s": 0.0, "encode_s": 0.0,
                       "scan_s": 0.0, "put_s": 0.0, "fsync_s": 0.0,
                       "kernel_bytes": 0, "host_scan_bytes": 0}
 
@@ -155,8 +156,14 @@ class AssiseCheckpointer:
     def save(self, step: int, state: Any, extra: Optional[dict] = None):
         """Write one checkpoint. state: pytree of arrays (numpy/JAX)."""
         self.wait()  # serialize with any pending async commit
-        t0 = time.monotonic()
         leaves = _flatten(state)
+        with self.store.tracer.span("ckpt.save", step=step,
+                                    leaves=len(leaves)):
+            self._save(step, leaves, extra)
+
+    def _save(self, step: int, leaves: Dict[str, Any],
+              extra: Optional[dict]):
+        span = self.store.tracer.span
         manifest = {"step": step, "leaves": sorted(leaves),
                     "extra": extra or {},
                     "format": "range" if self.cfg.delta else "full",
@@ -164,27 +171,30 @@ class AssiseCheckpointer:
         new_prev = {}
         st = self.stats
         for name, arr in leaves.items():
-            t = time.perf_counter()
-            host = np.asarray(arr)  # device leaves copy out one at a time
-            st["d2h_s"] += time.perf_counter() - t
-            t = time.perf_counter()
-            raw = _encode_leaf(host)
-            del host
-            manifest["leaf_crc"][name] = zlib.crc32(raw) & 0xFFFFFFFF
-            st["encode_s"] += time.perf_counter() - t
+            with span("ckpt.d2h") as sp:
+                host = np.asarray(arr)  # device leaves copy out one by one
+                sp.count(nbytes=host.nbytes)
+            st["d2h_s"] += sp.seconds
+            with span("ckpt.encode", nbytes=host.nbytes) as sp:
+                raw = _encode_leaf(host)
+                del host
+                manifest["leaf_crc"][name] = zlib.crc32(raw) & 0xFFFFFFFF
+            st["encode_s"] += sp.seconds
             st["bytes_full"] += len(raw)
             key = self._leaf_key(step, name)
             old = self._prev.get(name) if self.cfg.delta else None
             extents = None
             if old is not None and len(old) == len(raw):
-                t = time.perf_counter()
-                idxs, aligned = changed_block_idxs(
-                    raw, old, self.cfg.delta_block, self._scan)
+                with span("ckpt.scan") as sp:
+                    idxs, aligned = changed_block_idxs(
+                        raw, old, self.cfg.delta_block, self._scan)
+                    extents = changed_extents(raw, old, self.cfg.delta_block,
+                                              idxs=idxs)
+                    sp.count(kernel_bytes=aligned,
+                             host_bytes=len(raw) - aligned)
+                st["scan_s"] += sp.seconds
                 st["kernel_bytes"] += aligned
                 st["host_scan_bytes"] += len(raw) - aligned
-                extents = changed_extents(raw, old, self.cfg.delta_block,
-                                          idxs=idxs)
-                st["scan_s"] += time.perf_counter() - t
             t = time.perf_counter()
             if extents is not None and \
                     sum(ln for _, ln in extents) < len(raw):
@@ -219,7 +229,6 @@ class AssiseCheckpointer:
         self._prev = new_prev
         self._saved_steps.append(step)
         self.stats["saves"] += 1
-        self.stats["commit_s"] += time.monotonic() - t0
         self._gc()
 
     def wait(self):
@@ -253,6 +262,10 @@ class AssiseCheckpointer:
         the step the manifests agree is latest can be reassembled;
         asking for an older range-format step returns None."""
         self.wait()
+        with self.store.tracer.span("ckpt.restore") as sp:
+            return self._restore(step, sp)
+
+    def _restore(self, step: Optional[int], sp):
         if step is None:
             step = self.latest_step()
         if step is None:
@@ -263,22 +276,28 @@ class AssiseCheckpointer:
         m = json.loads(man)
         if m.get("format") == "range" and step != self.latest_step():
             return None  # stable keys already carry later steps' ranges
+        sp.count(leaves=len(m["leaves"]))
+        span = self.store.tracer.span
         out = {}
         crcs = m.get("leaf_crc", {})
         for name in m["leaves"]:
             key = f"{self.cfg.prefix}/data{name}" \
                 if m.get("format") == "range" \
                 else f"{self.cfg.prefix}/data/{step}{name}"
-            raw = self.store.get(key)
+            with span("ckpt.read") as sp:
+                raw = self.store.get(key)
+                sp.count(nbytes=len(raw) if raw is not None else 0)
             if raw is None:
                 return None
-            if m.get("format") == "range" and name in crcs \
-                    and (zlib.crc32(raw) & 0xFFFFFFFF) != crcs[name]:
-                # a crash mid-save left partial range patches of a NEWER
-                # step on the stable key: the set is unrestorable — fail
-                # loudly rather than hand back silently corrupt tensors
-                return None
-            out[name] = _decode_leaf(raw)
+            with span("ckpt.decode", nbytes=len(raw)):
+                if m.get("format") == "range" and name in crcs \
+                        and (zlib.crc32(raw) & 0xFFFFFFFF) != crcs[name]:
+                    # a crash mid-save left partial range patches of a
+                    # NEWER step on the stable key: the set is
+                    # unrestorable — fail loudly rather than hand back
+                    # silently corrupt tensors
+                    return None
+                out[name] = _decode_leaf(raw)
         return out, m
 
 

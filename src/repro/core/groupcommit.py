@@ -579,9 +579,12 @@ class GroupCommitCoordinator:
                     break
                 pending.append(nxt)
             try:
-                for framed, _done, _err in pending:
-                    self.journal.append_raw(framed)
-                self.journal.sync()
+                with self.sfs.transport.tracer.span(
+                        "store.persist",
+                        nbytes=sum(len(f) for f, _d, _e in pending)):
+                    for framed, _done, _err in pending:
+                        self.journal.append_raw(framed)
+                    self.journal.sync()
             except BaseException as e:  # noqa: BLE001
                 for _framed, _done, err in pending:
                     err.append(e)
@@ -633,7 +636,9 @@ class GroupCommitCoordinator:
         ctxs = [p[0].ctx for p in grp if p[0].ctx is not None]
         tok = tracer.push(ctxs[0]) if tracer is not None and ctxs else None
         try:
-            with tr.act_as(wnode):
+            with tr.act_as(wnode), tracer.span(
+                    "store.replicate", nbytes=len(framed),
+                    members=len(grp)):
                 acks = with_retries(_attempt, stats=tr.stats)
         finally:
             if tracer is not None and ctxs:

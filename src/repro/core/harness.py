@@ -258,15 +258,18 @@ class AssiseCluster:
         (test/bench convenience; production uses the 1s heartbeat loop).
         Simultaneous deaths are handled as ONE membership change: one
         epoch bump covers the whole batch."""
-        self.heartbeat_all()
-        failed = [n for n in self.node_ids
-                  if n in self.dead_nodes and self.cm.nodes[n].alive]
-        if failed:
-            self.cm.on_nodes_failed(failed)  # idempotent per death
-        if self.auto_rereplicate:
-            # every sweep, not only failure sweeps: a chain left short
-            # when no candidate was alive refills once nodes rejoin
-            self._rereplicate()
+        with self.transport.tracer.span("cluster.failover") as sp:
+            self.heartbeat_all()
+            failed = [n for n in self.node_ids
+                      if n in self.dead_nodes and self.cm.nodes[n].alive]
+            sp.count(failed=len(failed))
+            if failed:
+                self.cm.on_nodes_failed(failed)  # idempotent per death
+            if self.auto_rereplicate:
+                # every sweep, not only failure sweeps: a chain left
+                # short when no candidate was alive refills once nodes
+                # rejoin
+                self._rereplicate()
         return failed
 
     # -- background re-replication ------------------------------------------------
@@ -331,8 +334,9 @@ class AssiseCluster:
         ctx.annotate("failover.target", node=target, proc=proc_id)
         tok = tracer.push(ctx)
         try:
-            ls = self._failover_process(proc_id, subtree, fast, chain,
-                                        reserves, target, sfs, ctx)
+            with tracer.span("cluster.failover", target=target):
+                ls = self._failover_process(proc_id, subtree, fast, chain,
+                                            reserves, target, sfs, ctx)
         finally:
             tracer.pop(tok)
         self.procs[proc_id] = ls
@@ -400,6 +404,11 @@ class AssiseCluster:
     # -- observability accessors (DESIGN.md §5.5) -------------------------------
     def set_trace_sampling(self, sampling: float) -> None:
         self.transport.tracer.set_sampling(sampling)
+
+    def set_span_sink(self, sink) -> None:
+        """Where interval spans go (``Tracer`` explains ``sink``): the
+        JAX profiler by default, None for none."""
+        self.transport.tracer.sink = sink
 
     def flight_recording(self, node_id: str, kind: Optional[str] = None):
         """The node's flight-recorder ring, oldest first — readable

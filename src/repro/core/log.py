@@ -184,6 +184,7 @@ class UpdateLog:
         self._sealed: Optional[SealedRegion] = None
         self.index = {}  # combined view: sealed + active entries
         self.bytes = 0   # ACTIVE-region bytes (digest-threshold metric)
+        self._unflushed = 0  # bytes appended since the last flush
         # file-handle lock: the digest worker rotates the backing file
         # (reap_files) while the writer keeps appending
         self._file_lock = threading.RLock()
@@ -209,6 +210,7 @@ class UpdateLog:
         enc = e.encode()
         with self._file_lock:
             self._f.write(enc)
+            self._unflushed += len(enc)
             self._entries.append(e)
             self._offsets.append(len(self._buf))
             self._seqnos.append(e.seqno)
@@ -217,12 +219,15 @@ class UpdateLog:
         self._apply_to_index(e)
         return e
 
-    def persist(self) -> None:
-        """Flush to the persistence domain (CLWB+SFENCE analogue)."""
+    def persist(self) -> int:
+        """Flush to the persistence domain (CLWB+SFENCE analogue).
+        Returns the bytes appended since the previous flush."""
         with self._file_lock:
             self._f.flush()
             if self.fsync_data:
                 os.fsync(self._f.fileno())
+            n, self._unflushed = self._unflushed, 0
+        return n
 
     def flush_to_os(self) -> None:
         """Flush buffered appends to the OS *without* forcing them to
@@ -231,6 +236,7 @@ class UpdateLog:
         batch durable with one fsync (see groupcommit.py)."""
         with self._file_lock:
             self._f.flush()
+            self._unflushed = 0
 
     def _apply_to_index(self, e: Entry) -> None:
         if e.op == OP_PUT:

@@ -32,10 +32,18 @@ Span timestamps pair the (possibly simulated) cluster clock with a
 process-global sequence number taken under one lock: a sim clock may
 not advance between spans, so ordering assertions use ``seq`` while
 ``t`` carries the clock reading (non-decreasing in recorded order).
+
+Interval spans (``Tracer.span``) time one stage of work from entry to
+exit, whatever the sampling: each goes to the tracer's sink — by
+default the JAX profiler's ``TraceAnnotation`` (``assise.<name>``, on
+the host plane and clock of the device ops) where the process has JAX
+loaded — and, where a sampled op trace is current, into that trace
+with its duration.
 """
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -186,9 +194,10 @@ _NO_CTX = object()  # push() token meaning "nothing was pushed"
 
 
 class Span:
-    """One recorded protocol stage inside a trace."""
+    """One recorded protocol stage inside a trace. ``dur`` is None for
+    a point annotation, the clock's seconds for an interval span."""
 
-    __slots__ = ("seq", "t", "name", "node", "meta")
+    __slots__ = ("seq", "t", "name", "node", "meta", "dur")
 
     def __init__(self, seq, t, name, node, meta):
         self.seq = seq
@@ -196,11 +205,14 @@ class Span:
         self.name = name
         self.node = node
         self.meta = meta
+        self.dur = None
 
     def to_dict(self) -> dict:
         d = {"seq": self.seq, "t": self.t, "name": self.name}
         if self.node is not None:
             d["node"] = self.node
+        if self.dur is not None:
+            d["dur"] = self.dur
         if self.meta:
             d.update(self.meta)
         return d
@@ -230,13 +242,90 @@ class TraceCtx:
         return f"TraceCtx({self.trace_id}, op={self.op})"
 
 
+def profiler_sink(name: str, counts: dict):
+    """The default span sink: a ``jax.profiler.TraceAnnotation`` named
+    ``assise.<name>`` with the counts as its stats, where this process
+    has JAX loaded. Resolved through ``sys.modules`` so that nothing
+    here imports JAX; with no profiler running, the annotation does
+    nothing past its own active check."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation("assise." + name, **counts)
+
+
+_CURRENT = object()  # span(ctx=...) default: the thread's active trace
+
+
+class IntervalSpan:
+    """One stage of work timed from entry to exit; made by
+    ``Tracer.span``. ``seconds`` is its duration on ``perf_counter``;
+    ``count`` adds counts known only once the work is done."""
+
+    __slots__ = ("tracer", "name", "counts", "start", "end", "_ctx",
+                 "_trace_as", "_meta", "_rec", "_sunk")
+
+    def __init__(self, tracer, name, ctx, trace_as, meta, counts):
+        self.tracer = tracer
+        self.name = name
+        self.counts = counts
+        self._ctx = ctx
+        self._trace_as = trace_as
+        self._meta = meta
+        self._rec = self._sunk = None
+        self.start = self.end = 0.0
+
+    def __enter__(self) -> "IntervalSpan":
+        tr = self.tracer
+        ctx = tr.current() if self._ctx is _CURRENT else self._ctx
+        if ctx is not None:
+            meta = {k: v for k, v in self.counts.items() if k != "node"}
+            if self._meta:
+                meta.update(self._meta)
+            self._rec = tr.record(ctx, self._trace_as or self.name,
+                                  self.counts.get("node"), meta or None)
+        if tr.sink is not None:
+            sunk = tr.sink(self.name, self.counts)
+            if sunk is not None:
+                sunk.__enter__()
+                self._sunk = sunk
+        self.start = time.perf_counter()
+        return self
+
+    def count(self, **counts) -> None:
+        self.counts.update(counts)
+        if self._sunk is not None and hasattr(self._sunk, "set_metadata"):
+            self._sunk.set_metadata(**counts)
+        if self._rec is not None:
+            self._rec.meta = dict(self._rec.meta or {}, **counts)
+
+    def __exit__(self, *exc) -> bool:
+        self.end = time.perf_counter()
+        if self._rec is not None:
+            self._rec.dur = self.tracer.clock() - self._rec.t
+        if self._sunk is not None:
+            self._sunk.__exit__(*exc)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
 class Tracer:
     """Cluster-wide span collector with deterministic sampling and a
-    thread-local active context (the in-process header register)."""
+    thread-local active context (the in-process header register).
+
+    ``sink(name, counts)`` receives every interval span: it returns a
+    context manager entered at the span's entry and exited at its exit
+    (``counts`` is the span's own dict, extended by ``count``), or None.
+    The default is the JAX profiler; None turns interval spans into
+    bare timers."""
 
     def __init__(self, clock=time.monotonic, sampling: float = 1 / 64,
-                 max_traces: int = 512):
+                 max_traces: int = 512, sink=profiler_sink):
         self.clock = clock
+        self.sink = sink
         self.set_sampling(sampling)
         self.max_traces = max_traces
         self._traces: "OrderedDict[int, list]" = OrderedDict()
@@ -307,16 +396,29 @@ class Tracer:
         self._tls.ctx = token
 
     # -- recording ---------------------------------------------------------
-    def record(self, ctx: TraceCtx, name: str, node=None, meta=None) -> None:
+    def record(self, ctx: TraceCtx, name: str, node=None, meta=None):
+        """Appends a span to ``ctx``'s trace and returns it (None where
+        the trace was evicted)."""
         # seq + clock are taken under the lock so list order == seq
         # order and t is non-decreasing in list order even across
         # threads (monotonic clock) — the property trace tests assert.
         with self._lock:
             spans = self._traces.get(ctx.trace_id)
             if spans is None:
-                return
-            spans.append(Span(next(_SPAN_SEQ), self.clock(),
-                              name, node, meta))
+                return None
+            span = Span(next(_SPAN_SEQ), self.clock(), name, node, meta)
+            spans.append(span)
+            return span
+
+    def span(self, name: str, *, ctx=_CURRENT, trace_as=None, meta=None,
+             **counts) -> IntervalSpan:
+        """``with tracer.span("store.append", nbytes=n) as sp:`` times
+        one operation. The counts (bytes, leaves, entries; ``node``
+        names the node doing the work) go to the sink and, where an op
+        trace is current (or ``ctx`` is given), into that trace as a
+        span named ``trace_as`` (default ``name``) with ``meta`` added,
+        made at entry and given its duration at exit."""
+        return IntervalSpan(self, name, ctx, trace_as, meta, counts)
 
     # -- inspection --------------------------------------------------------
     def spans(self, trace_id) -> list:

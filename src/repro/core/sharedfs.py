@@ -327,18 +327,22 @@ class SharedFS:
         acked — the coalesced stream is replay-equivalent — and is
         likewise skipped rather than replayed over newer state."""
         slot = self.slot_for(proc_id)
-        if data:
-            slot.write(None, data)
-        if rest:
-            head, tail = rest[0], rest[1:]
-            # a middle replica dying right here leaves the prefix acked
-            # nowhere: the writer sees NodeDown, the op is not acked
-            self.transport.crashpoint("chain.fwd", self.node_id)
-            self.transport.one_sided_write(head, f"slot/{proc_id}", data,
-                                           _epoch=self.view_epoch)
-            return self.transport.rpc(head, "chain_continue", proc_id, data,
-                                      tail, _epoch=self.view_epoch)
-        return slot.acked_seqno
+        with self.transport.tracer.span("repl.hop", node=self.node_id,
+                                        nbytes=len(data)):
+            if data:
+                slot.write(None, data)
+            if rest:
+                head, tail = rest[0], rest[1:]
+                # a middle replica dying right here leaves the prefix
+                # acked nowhere: the writer sees NodeDown, the op is not
+                # acked
+                self.transport.crashpoint("chain.fwd", self.node_id)
+                self.transport.one_sided_write(head, f"slot/{proc_id}",
+                                               data, _epoch=self.view_epoch)
+                return self.transport.rpc(head, "chain_continue", proc_id,
+                                          data, tail,
+                                          _epoch=self.view_epoch)
+            return slot.acked_seqno
 
     # -- group commit (cross-process batch replication) ------------------------
     def ensure_group_sink(self, writer_node: str) -> None:
@@ -415,20 +419,25 @@ class SharedFS:
         with self._slot_digest_lock(proc_id):
             slot = self.slot_for(proc_id)
             batch = [e for e in slot.entries if e.seqno <= through_seqno]
-            self._apply_batch(batch)
-            with self._commit_lock:
-                self._evict_if_needed()
-                self._commit_areas()
-            # dying here (applied, not yet truncated) is safe exactly
-            # because re-digesting the same slot prefix is idempotent
-            self.transport.crashpoint("digest.mid", self.node_id)
-            # truncate only after the applied entries are durable in the
-            # areas — a crash in between must never lose the digested range
-            slot.truncate_through(through_seqno)
+            with self.transport.tracer.span(
+                    "store.digest_apply", trace_as="digest.apply",
+                    meta={"proc": proc_id, "upto": through_seqno,
+                          "applied": len(batch)},
+                    node=self.node_id,
+                    nbytes=sum(e.nbytes for e in batch)):
+                self._apply_batch(batch)
+                with self._commit_lock:
+                    self._evict_if_needed()
+                    self._commit_areas()
+                # dying here (applied, not yet truncated) is safe exactly
+                # because re-digesting the same slot prefix is idempotent
+                self.transport.crashpoint("digest.mid", self.node_id)
+                # truncate only after the applied entries are durable in
+                # the areas — a crash in between must never lose the
+                # digested range
+                slot.truncate_through(through_seqno)
             self.stats["digests"] += 1
             self.recorder.record("digest", f"slot:{proc_id}@{through_seqno}")
-            self._span("digest.apply", proc=proc_id, upto=through_seqno,
-                       applied=len(batch))
             return len(batch)
 
     def digest_slot_chain(self, proc_id: str, through_seqno: int,
@@ -444,17 +453,21 @@ class SharedFS:
         return applied
 
     def digest_entries(self, entries: List[L.Entry]) -> int:
-        self._apply_batch(entries)
-        with self._commit_lock:
-            # node dies mid-digest, before the area commit: the applied
-            # batch is buffered, not durable — recovery replays it from
-            # the replicated log (slots), never from the torn area
-            self.transport.crashpoint("digest.apply", self.node_id)
-            self.stats["digests"] += 1
-            self._evict_if_needed()
-            self._commit_areas()
+        with self.transport.tracer.span(
+                "store.digest_apply", trace_as="digest.apply",
+                meta={"applied": len(entries)}, node=self.node_id,
+                nbytes=sum(e.nbytes for e in entries)):
+            self._apply_batch(entries)
+            with self._commit_lock:
+                # node dies mid-digest, before the area commit: the
+                # applied batch is buffered, not durable — recovery
+                # replays it from the replicated log (slots), never from
+                # the torn area
+                self.transport.crashpoint("digest.apply", self.node_id)
+                self.stats["digests"] += 1
+                self._evict_if_needed()
+                self._commit_areas()
         self.recorder.record("digest", f"entries:{len(entries)}")
-        self._span("digest.apply", applied=len(entries))
         return len(entries)
 
     def _commit_areas(self) -> None:

@@ -463,11 +463,11 @@ class LibState:
         t0 = time.perf_counter()
         self._lease(path, WRITE)
         ctx = self._trace_write()
-        self.log.append(L.OP_PUT, path, data)
+        with self.tracer.span("store.append", ctx=ctx, trace_as="append",
+                              meta={"path": path}, node=self.sfs.node_id,
+                              nbytes=len(data)):
+            self.log.append(L.OP_PUT, path, data)
         self.stats["puts"] += 1
-        if ctx is not None:
-            ctx.annotate("append", node=self.sfs.node_id, path=path,
-                         nbytes=len(data))
         self.dram.invalidate(path)
         self._neg.pop(path, None)
         if self.log.bytes >= self.digest_threshold * self.log.capacity:
@@ -482,11 +482,11 @@ class LibState:
         t0 = time.perf_counter()
         self._lease(path, WRITE)
         ctx = self._trace_write()
-        self.log.append(L.OP_WRITE, path, data, offset)
+        with self.tracer.span("store.append", ctx=ctx, trace_as="append",
+                              meta={"path": path, "offset": offset},
+                              node=self.sfs.node_id, nbytes=len(data)):
+            self.log.append(L.OP_WRITE, path, data, offset)
         self.stats["range_writes"] += 1
-        if ctx is not None:
-            ctx.annotate("append", node=self.sfs.node_id, path=path,
-                         nbytes=len(data), offset=offset)
         self.dram.invalidate(path)
         self._neg.pop(path, None)
         if self.log.bytes >= self.digest_threshold * self.log.capacity:
@@ -496,7 +496,8 @@ class LibState:
 
     def _threshold_digest(self) -> None:
         if not self.pipeline_digests:
-            self.digest()  # pre-pipeline behavior: digest inline
+            with self.tracer.span("store.digest", nbytes=self.log.bytes):
+                self.digest()  # pre-pipeline behavior: digest inline
             return
         job = self._inflight
         if job is not None and not job.done \
@@ -509,7 +510,10 @@ class LibState:
             # point: seal_and_digest below then blocks on the reap.
             self.stats["seal_deferrals"] += 1
             return
-        self.seal_and_digest()
+        # the writer's blocking part: backpressure on the previous
+        # digest, the seal and its persist; the rest runs on the worker
+        with self.tracer.span("store.digest", nbytes=self.log.bytes):
+            self.seal_and_digest()
 
     def delete(self, path: str) -> None:
         self._lease(path, WRITE)
@@ -583,11 +587,11 @@ class LibState:
                     # amortized away
                     gc.commit(self, coalesce=False)
                 else:
-                    self.log.persist()
+                    self._persist()
                     with self._repl_lock:
                         self._replicate(coalesce=False)
             else:
-                self.log.persist()
+                self._persist()
             if ctx is not None:
                 ctx.annotate("ack", node=self.sfs.node_id)
                 ctx.acked = True
@@ -611,7 +615,7 @@ class LibState:
             if gc is not None and self._group_commit:
                 gc.commit(self, coalesce=(self.mode == "optimistic"))
             else:
-                self.log.persist()
+                self._persist()
                 with self._repl_lock:
                     self._replicate(coalesce=(self.mode == "optimistic"))
             if ctx is not None:
@@ -625,24 +629,34 @@ class LibState:
             self.metrics.observe("op.dsync.us",
                                  (time.perf_counter() - t0) * 1e6)
 
+    def _persist(self) -> None:
+        """The log's flush to the persistence domain, as one span."""
+        with self.tracer.span("store.persist") as sp:
+            sp.count(nbytes=self.log.persist())
+
     def _replicate(self, coalesce: bool) -> None:
         """Replicate everything past the chain's watermark — spanning a
         seal boundary if one is pending. Caller holds ``_repl_lock``.
         Any pipelined sealed-region ship is settled first so the slice
         computed here starts exactly where the wire stream left off."""
-        self.chain.wait_acked(self.chain.submitted_seqno)
-        since = self.chain.submitted_seqno
-        pending = self.log.entries_since(since)
-        if not pending:
-            return
-        if coalesce:
-            reduced = UpdateLog.coalesce(pending)
-            self.stats["coalesced_out"] += len(pending) - len(reduced)
-            self.chain.replicate(reduced)
-            self.chain.mark_acked(pending[-1].seqno)
-        else:
-            # zero-copy: ship the log's pre-encoded byte range as-is
-            self.chain.replicate(pending, self.log.encoded_since(since))
+        with self.tracer.span("store.replicate") as sp:
+            self.chain.wait_acked(self.chain.submitted_seqno)
+            since = self.chain.submitted_seqno
+            pending = self.log.entries_since(since)
+            if not pending:
+                return
+            if coalesce:
+                reduced = UpdateLog.coalesce(pending)
+                self.stats["coalesced_out"] += len(pending) - len(reduced)
+                sp.count(entries=len(reduced),
+                         nbytes=sum(e.nbytes for e in reduced))
+                self.chain.replicate(reduced)
+                self.chain.mark_acked(pending[-1].seqno)
+            else:
+                # zero-copy: ship the log's pre-encoded byte range as-is
+                data = self.log.encoded_since(since)
+                sp.count(entries=len(pending), nbytes=len(data))
+                self.chain.replicate(pending, data)
 
     # -- read path ------------------------------------------------------------
     _MISS = object()
@@ -1100,7 +1114,7 @@ class LibState:
                 self.sfs.drain_digests()
                 self._settle_before_digest = False
             self._reap(wait=True)
-            self.log.persist()
+            self._persist()
             with self._repl_lock:
                 self._replicate(coalesce=(self.mode == "optimistic"))
             upto = self.log.last_seqno

@@ -319,8 +319,9 @@ def rwkv_time_mix(params: dict, x: Array, spec: RWKVSpec, *, chunk: int = 64,
             y, st_new = _rwkv_chunk(rc, kc, vc, lwc, u, st)
             return st_new, y
 
-        state, ys = jax.lax.scan(body, state0,
-                                 (split(r), split(k), split(v), split(logw)))
+        chunks = (split(r), split(k), split(v), split(logw))
+        with jax.named_scope("wkv"):  # op events in a profile carry it
+            state, ys = jax.lax.scan(body, state0, chunks)
         y = ys.transpose(1, 0, 2, 3, 4).reshape(b, sp, h, dh)[:, :s]
         new_cache = {"shift_tm": x[:, -1], "wkv": state} \
             if cache is not None else None

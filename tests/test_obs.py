@@ -421,3 +421,124 @@ def test_flight_recorder_records_injected_faults(tmp_path):
         assert faults and faults[0][3].startswith("dup:rpc:")
     finally:
         c.close()
+
+
+# -- interval spans -----------------------------------------------------------
+
+class _Sink:
+    """A span sink that records (event, name, counts) at entry and exit."""
+
+    def __init__(self):
+        self.events = []
+
+    def __call__(self, name, counts):
+        sink = self
+
+        class _Open:
+            def __enter__(self):
+                sink.events.append(("enter", name, dict(counts)))
+
+            def __exit__(self, *exc):
+                sink.events.append(("exit", name, dict(counts)))
+
+        return _Open()
+
+
+def test_span_nests_in_entry_order_and_fills_duration():
+    t = [0.0]
+    tr = Tracer(clock=lambda: t[0], sampling=1.0, sink=None)
+    ctx = tr.start("op.test")
+    tok = tr.push(ctx)
+    try:
+        with tr.span("outer", nbytes=8):
+            t[0] = 1.0
+            with tr.span("inner", trace_as="inner.op", meta={"k": 1}):
+                t[0] = 3.0
+            t[0] = 4.0
+    finally:
+        tr.pop(tok)
+    spans = tr.spans(ctx.trace_id)
+    assert [s.name for s in spans] == ["op.test", "outer", "inner.op"]
+    _assert_ordered(spans)
+    assert spans[0].dur is None  # a point annotation stays a point
+    assert spans[1].dur == 4.0 and spans[2].dur == 2.0
+    assert spans[1].meta == {"nbytes": 8} and spans[2].meta == {"k": 1}
+    assert spans[2].to_dict()["dur"] == 2.0
+
+
+def test_span_passes_counts_to_the_sink():
+    sink = _Sink()
+    tr = Tracer(sink=sink)
+    with tr.span("store.replicate", node="node1", nbytes=10) as sp:
+        sp.count(entries=3)
+    assert sink.events == [
+        ("enter", "store.replicate", {"node": "node1", "nbytes": 10}),
+        ("exit", "store.replicate",
+         {"node": "node1", "nbytes": 10, "entries": 3})]
+    assert sp.seconds >= 0 and sp.counts["entries"] == 3
+
+
+def test_span_without_sink_or_trace_records_nothing():
+    tr = Tracer(sampling=1.0, sink=None)
+    with tr.span("ckpt.d2h", nbytes=4) as sp:
+        pass
+    assert tr.traces() == [] and sp.seconds >= 0
+    sink = _Sink()
+    tr = Tracer(sampling=1.0, sink=sink)
+    ctx = tr.start("op.other")  # started, but not current on this thread
+    with tr.span("ckpt.d2h"):
+        pass
+    assert [s.name for s in tr.spans(ctx.trace_id)] == ["op.other"]
+    assert [e[0] for e in sink.events] == ["enter", "exit"]
+
+
+def test_span_closes_when_its_work_raises():
+    sink = _Sink()
+    tr = Tracer(clock=lambda: 5.0, sampling=1.0, sink=sink)
+    ctx = tr.start("op.fail")
+    tok = tr.push(ctx)
+    try:
+        with pytest.raises(ValueError):
+            with tr.span("store.persist") as sp:
+                raise ValueError("boom")
+    finally:
+        tr.pop(tok)
+    assert [e[0] for e in sink.events] == ["enter", "exit"]
+    assert tr.spans(ctx.trace_id)[-1].dur == 0.0
+    assert sp.end >= sp.start > 0
+
+
+def test_default_sink_is_the_jax_profiler_only_where_jax_is_loaded(
+        monkeypatch):
+    import sys
+
+    from repro.core.obs import profiler_sink
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
+    assert profiler_sink("x", {}) is None
+    assert Tracer().sink is profiler_sink
+
+
+def test_cluster_spans_reach_the_sink_set_on_the_cluster(tmp_path):
+    c = make(tmp_path, replication=3)
+    try:
+        sink = _Sink()
+        c.set_span_sink(sink)
+        ls = c.open_process("p", "node0")
+        ls.put("/sp/x", b"v" * 64)
+        ls.fsync()
+        entered = [(n, k) for e, n, k in sink.events if e == "enter"]
+        assert ("store.append", {"node": "node0", "nbytes": 64}) in entered
+        assert {n for n, _ in entered} >= {"store.persist",
+                                            "store.replicate", "repl.hop"}
+        hops = [k["node"] for e, n, k in sink.events
+                if e == "enter" and n == "repl.hop"]
+        assert hops == ["node1", "node2"]  # one span per hop
+        done = {n: k for e, n, k in sink.events if e == "exit"}
+        assert done["store.replicate"]["entries"] == 1
+        assert done["store.persist"]["nbytes"] > 64
+        c.set_span_sink(None)
+        n = len(sink.events)
+        ls.put("/sp/y", b"v")
+        assert len(sink.events) == n
+    finally:
+        c.close()
