@@ -243,29 +243,119 @@ def _rwkv_proj(params, xs, h, dh):
     return r, k, v, g, logw
 
 
+_SUB_BLOCK = 16  # tokens per diagonal sub-block of a wkv chunk
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def _sub_block_decays(ex, lwc):
+    """exp(ex_t - lwc_s) <= 1 for s < t inside each sub-block, else 0.
+    ex/lwc:(B,H,n,S,dk). Returns (B,H,n,St,Ss,dk)."""
+    sub = ex.shape[3]
+    diff = ex[:, :, :, :, None] - lwc[:, :, :, None]
+    tri = jnp.tril(jnp.ones((sub, sub), jnp.float32), k=-1)
+    return jnp.exp(jnp.minimum(diff, 0.0)) * tri[:, :, None]
+
+
+@jax.custom_vjp
+def _sub_block_scores(r, k, ex, lwc):
+    """sum_d r_t k_s exp(ex_t - lwc_s) over the pairs s < t of each
+    sub-block. r/k/ex/lwc:(B,H,n,S,dk). Returns (B,H,n,St,Ss)."""
+    return jnp.einsum("bhntd,bhnsd,bhntsd->bhnts", r, k,
+                      _sub_block_decays(ex, lwc))
+
+
+def _sub_block_scores_fwd(r, k, ex, lwc):
+    return _sub_block_scores(r, k, ex, lwc), (r, k, ex, lwc)
+
+
+def _sub_block_scores_bwd(res, da):
+    # two passes over the decays, one for dr and one for dk: ex and lwc
+    # enter only through exp(ex_t - lwc_s), so theirs are r*dr and -k*dk
+    r, k, ex, lwc = res
+    w = da[..., None] * _sub_block_decays(ex, lwc)  # (B,H,n,St,Ss,dk)
+    dr = jnp.sum(w * k[:, :, :, None], axis=4)
+    dk = jnp.sum(w * r[:, :, :, :, None], axis=3)
+    return dr, dk, r * dr, -(k * dk)
+
+
+_sub_block_scores.defvjp(_sub_block_scores_fwd, _sub_block_scores_bwd)
+
+
 def _rwkv_chunk(r, k, v, logw, u, state0):
-    """One wkv chunk. r/k/v/logw:(B,L,H,dk|dv), state0:(B,H,dk,dv) f32."""
+    """One wkv chunk. r/k/v/logw:(B,H,L,dk|dv), state0:(B,H,dk,dv) f32.
+
+    The chunk runs as sub-blocks of S = min(16, L) tokens (the last one
+    padded). Inside a sub-block the pairwise decays exp(ex_t - lwc_s) are
+    formed exactly, a (B,H,n,S,S,dk) tensor. Across sub-blocks they factor
+    through the state carried from one sub-block to the next: r is scaled
+    by the decay since its sub-block's start, k by the decay to its end,
+    both exp(<= 0), and the products run on the MXU at f32 precision.
+    With L <= 16 there is one sub-block and this is the plain chunk form.
+    """
     rf = r.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    lwc = jnp.cumsum(logw, axis=1)  # inclusive
-    ex = lwc - logw  # exclusive
-    # inter-chunk: r_t . (exp(ex_t) * S0)
-    y_inter = jnp.einsum("blhd,bhdv->blhv", rf * jnp.exp(ex), state0)
-    # intra-chunk pairwise decays (strictly s < t): exp(ex_t - lwc_s) <= 1
-    diff = ex[:, :, None] - lwc[:, None, :]  # (B,Lt,Ls,H,dk)
-    tri = jnp.tril(jnp.ones((r.shape[1], r.shape[1]), jnp.float32), k=-1)
-    pair = jnp.exp(jnp.minimum(diff, 0.0)) * tri[None, :, :, None, None]
-    amat = jnp.einsum("bthd,bshd,btshd->bhts", rf, kf, pair)
-    diag = jnp.einsum("bthd,hd,bthd->bth", rf, u, kf)  # bonus on s=t
-    y_intra = jnp.einsum("bhts,bshv->bthv", amat, vf) \
-        + diag[..., None].transpose(0, 1, 2, 3) * vf
-    # new state: exp(lwc_L)*S0 + sum_s exp(lwc_L - lwc_s) k_s (x) v_s
-    w_all = jnp.exp(lwc[:, -1])  # (B,H,dk)
-    k_dec = kf * jnp.exp(lwc[:, -1][:, None] - lwc)
-    s_new = w_all[..., None] * state0 + jnp.einsum("bshd,bshv->bhdv", k_dec,
-                                                   vf)
-    return y_inter + y_intra, s_new
+    b, h, l, _ = rf.shape
+    sub = min(_SUB_BLOCK, l)
+    pad = (-l) % sub
+    if pad:  # identity padding: decay exp(0) = 1, k = v = 0
+        zp = lambda t: jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        rf, kf, vf, logw = zp(rf), zp(kf), zp(vf), zp(logw)
+    n = (l + pad) // sub
+
+    def blocks(t):
+        return t.reshape(b, h, n, sub, t.shape[-1])
+
+    rb, kb, vb, lw = blocks(rf), blocks(kf), blocks(vf), blocks(logw)
+    lwc = jnp.cumsum(lw, axis=3)  # inclusive, from the sub-block's start
+    ex = lwc - lw  # exclusive
+    amat = _sub_block_scores(rb, kb, ex, lwc)
+    diag = jnp.einsum("bhntd,hd,bhntd->bhnt", rb, u, kb)  # bonus on s=t
+    y = jnp.einsum("bhnts,bhnsv->bhntv", amat, vb) + diag[..., None] * vb
+    # across sub-blocks: r_t * exp(ex_t) reads the carried state,
+    # k_s * exp(lwc_end - lwc_s) (x) v_s is added to it
+    q = rb * jnp.exp(ex)
+    end = lwc[:, :, :, -1:]
+    kdec = kb * jnp.exp(end - lwc)
+    state = state0
+    ys = []
+    for i in range(n):
+        ys.append(jnp.einsum("bhtd,bhdv->bhtv", q[:, :, i], state,
+                             precision=_EXACT))
+        state = jnp.exp(end[:, :, i, 0])[..., None] * state + jnp.einsum(
+            "bhsd,bhsv->bhdv", kdec[:, :, i], vb[:, :, i], precision=_EXACT)
+    y = y + jnp.stack(ys, axis=2)
+    return y.reshape(b, h, n * sub, -1)[:, :, :l], state
+
+
+def _wkv(r, k, v, logw, u, state0, chunk: int):
+    """The wkv scan over a sequence. r/k/v/logw:(B,S,H,dk|dv),
+    state0:(B,H,dk,dv) f32. Returns y:(B,S,H,dv) f32 and the final state."""
+    b, s, h, _ = r.shape
+    l = min(chunk, s)
+    pad = (-s) % l
+    if pad:
+        zp = lambda t: jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        r, k, v, logw = zp(r), zp(k), zp(v), zp(logw)
+    nc = (s + pad) // l
+
+    def split(t):  # (B,S,H,d) -> (nc,B,H,L,d)
+        return t.reshape(b, nc, l, h, t.shape[-1]).transpose(1, 0, 3, 2, 4)
+
+    # rematerialized per chunk: the backward pass keeps only each chunk's
+    # inputs and carried state, not its (B,H,n,S,S,dk) sub-block decays
+    # (at published widths 134 MB a chunk, 4 GB a step)
+    @partial(jax.checkpoint, prevent_cse=False)
+    def body(st, xs_):
+        rc, kc, vc, lwc = xs_
+        y, st_new = _rwkv_chunk(rc, kc, vc, lwc, u, st)
+        return st_new, y
+
+    chunks = (split(r), split(k), split(v), split(logw))
+    with jax.named_scope("wkv"):  # op events in a profile carry it
+        state, ys = jax.lax.scan(body, state0, chunks)
+    y = ys.transpose(1, 0, 3, 2, 4).reshape(b, s + pad, h, -1)[:, :s]
+    return y, state
 
 
 def rwkv_time_mix(params: dict, x: Array, spec: RWKVSpec, *, chunk: int = 64,
@@ -294,35 +384,9 @@ def rwkv_time_mix(params: dict, x: Array, spec: RWKVSpec, *, chunk: int = 64,
         y = y[:, None]  # (B,1,H,dv)
         new_cache = {"shift_tm": x[:, -1], "wkv": state}
     else:
-        l = min(chunk, s)
-        pad = (-s) % l
-        if pad:
-            zp = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
-                                   (t.ndim - 2))
-            r, k, v, logw = zp(r), zp(k), zp(v), zp(logw)
-        sp = s + pad
-        nc = sp // l
-
-        def split(t):
-            return t.reshape(b, nc, l, *t.shape[2:]).transpose(
-                1, 0, 2, *range(3, t.ndim + 1))
-
         state0 = cache["wkv"] if cache is not None else \
             jnp.zeros((b, h, dh, dh), jnp.float32)
-
-        # rematerialized per chunk: the backward pass keeps only each
-        # chunk's inputs and carried state, not its (B,L,L,H,dk) pairwise
-        # decays — at published widths those would take tens of GB
-        @partial(jax.checkpoint, prevent_cse=False)
-        def body(st, xs_):
-            rc, kc, vc, lwc = xs_
-            y, st_new = _rwkv_chunk(rc, kc, vc, lwc, u, st)
-            return st_new, y
-
-        chunks = (split(r), split(k), split(v), split(logw))
-        with jax.named_scope("wkv"):  # op events in a profile carry it
-            state, ys = jax.lax.scan(body, state0, chunks)
-        y = ys.transpose(1, 0, 2, 3, 4).reshape(b, sp, h, dh)[:, :s]
+        y, state = _wkv(r, k, v, logw, u, state0, chunk)
         new_cache = {"shift_tm": x[:, -1], "wkv": state} \
             if cache is not None else None
 

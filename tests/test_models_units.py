@@ -133,6 +133,126 @@ def test_rwkv_decode_matches_forward():
                                np.asarray(full[:, s]), atol=1e-3, rtol=1e-3)
 
 
+# logw ranges: no decay (the upper clip), a typical decay, near the lower clip
+LOGW_RANGES = {"flat": (-2e-5, -1e-5), "mid": (-0.9, -0.1),
+               "steep": (-20.0, -15.0)}
+RWKV_CASES = [(l, w) for l in (4, 16, 40, 64) for w in sorted(LOGW_RANGES)]
+
+
+def _wkv_tokens(r, k, v, logw, u, state0):
+    """The wkv recurrence one token at a time. r/k/v/logw:(B,T,H,d),
+    state0:(B,H,dk,dv). Returns y:(B,T,H,dv) and the final state."""
+    def step(st, xs):
+        rt, kt, vt, wt = xs  # (B,H,d)
+        kv = kt[..., :, None] * vt[..., None, :]
+        y = jnp.einsum("bhd,bhdv->bhv", rt, st + u[..., None] * kv,
+                       precision=jax.lax.Precision.HIGHEST)
+        return jnp.exp(wt)[..., None] * st + kv, y
+
+    state, ys = jax.lax.scan(step, state0, tuple(
+        t.swapaxes(0, 1) for t in (r, k, v, logw)))
+    return ys.swapaxes(0, 1), state
+
+
+def _rel_err(got, want, floor=0.0):
+    """Largest error over the largest reference value (or ``floor``)."""
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want))
+                 / max(np.max(np.abs(want)), floor))
+
+
+def _grads_close(grads, grads_ref, names):
+    """Each gradient within 1e-4 of its largest value. A gradient that the
+    steepest decays drive down to f32 rounding noise (logw's and w0's:
+    terms of order 1 cancel in it) is held to 1e-6 of the largest
+    gradient of all instead."""
+    floor = 1e-2 * max(float(np.max(np.abs(np.asarray(g))))
+                       for g in grads_ref)
+    for name, g, g_ref in zip(names, grads, grads_ref):
+        assert np.isfinite(np.asarray(g)).all(), name
+        assert _rel_err(g, g_ref, floor) < 1e-4, name
+
+
+@pytest.mark.parametrize("length,logw_range", RWKV_CASES)
+def test_rwkv_chunk_matches_token_recurrence(length, logw_range):
+    b, h, d = 2, 2, 8
+    lo, hi = LOGW_RANGES[logw_range]
+    rng = np.random.default_rng(length)
+    r, k, v = (jnp.asarray(rng.standard_normal((b, h, length, d)),
+                           jnp.float32) for _ in range(3))
+    logw = jnp.asarray(rng.uniform(lo, hi, (b, h, length, d)), jnp.float32)
+    u = jnp.asarray(rng.standard_normal((h, d)), jnp.float32)
+    state0 = jnp.asarray(rng.standard_normal((b, h, d, d)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((b, h, length, d)), jnp.float32)
+    cs = jnp.asarray(rng.standard_normal((b, h, d, d)), jnp.float32)
+
+    def tokens(r, k, v, logw, u, state0):  # (B,H,L,d) like the chunk
+        y, st = _wkv_tokens(*(t.swapaxes(1, 2) for t in (r, k, v, logw)),
+                            u, state0)
+        return y.swapaxes(1, 2), st
+
+    args = (r, k, v, logw, u, state0)
+    y, st = jax.jit(S._rwkv_chunk)(*args)
+    y_ref, st_ref = jax.jit(tokens)(*args)
+    assert _rel_err(y, y_ref) < 2e-5
+    assert _rel_err(st, st_ref) < 2e-5
+
+    def loss(fn):
+        def f(*a):
+            y, st = fn(*a)
+            return jnp.sum(y * ct) + jnp.sum(st * cs)
+        return jax.jit(jax.grad(f, argnums=tuple(range(6))))
+
+    _grads_close(loss(S._rwkv_chunk)(*args), loss(tokens)(*args),
+                 ("r", "k", "v", "logw", "u", "state0"))
+
+
+@pytest.mark.parametrize("length,logw_range", RWKV_CASES)
+def test_rwkv_time_mix_matches_token_recurrence(length, logw_range,
+                                                 monkeypatch):
+    """The chunked scan inside rwkv_time_mix (chunk = length, a sequence of
+    two chunks and a padded third) against the same layer with the
+    token-by-token recurrence in its place, from a non-zero state."""
+    spec = RWKVSpec(head_dim=8, decay_lora=8, mix_lora=4, d_ffn=32)
+    d_model, b, seq = 16, 2, 2 * length + 3
+    lo, hi = LOGW_RANGES[logw_range]
+    params = S.init_rwkv(jax.random.key(length), d_model, spec, jnp.float32)
+    # logw = -exp(w0 + lora), the lora term small: centre it in the range
+    params["w0"] = jnp.full((d_model,), np.log(-(lo + hi) / 2), jnp.float32)
+    params["dw2"] = params["dw2"] * 0.1
+    rng = np.random.default_rng(length + 1)
+    x = jnp.asarray(rng.standard_normal((b, seq, d_model)) * 0.3,
+                    jnp.float32)
+    cache = {"shift_tm": jnp.zeros((b, d_model), jnp.float32),
+             "wkv": jnp.asarray(rng.standard_normal((b, 2, 8, 8)),
+                                jnp.float32)}
+
+    def run(p, x, state0):
+        out, new = S.rwkv_time_mix(p, x, spec, chunk=length,
+                                   cache=dict(cache, wkv=state0),
+                                   mode="prefill")
+        return out, new["wkv"]
+
+    def loss(p, x, state0):
+        out, st = run(p, x, state0)
+        return jnp.sum(out ** 2) + jnp.sum(st ** 2)
+
+    def results():  # traced anew: reads S._wkv as it stands
+        return (jax.jit(run)(params, x, cache["wkv"]),
+                jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(
+                    params, x, cache["wkv"]))
+
+    got, grads = results()
+    monkeypatch.setattr(S, "_wkv", lambda r, k, v, logw, u, state0, chunk:
+                        _wkv_tokens(r, k, v, logw, u, state0))
+    want, grads_ref = results()
+    assert _rel_err(got[0], want[0]) < 2e-5
+    assert _rel_err(got[1], want[1]) < 2e-5
+    paths, leaves = zip(*jax.tree_util.tree_leaves_with_path(grads))
+    _grads_close(leaves, jax.tree_util.tree_leaves(grads_ref),
+                 [jax.tree_util.keystr(p) for p in paths])
+
+
 def test_moe_routing_sanity():
     spec = MoESpec(n_experts=4, top_k=2, d_expert=16, n_shared=1)
     d_model = 8
