@@ -14,6 +14,7 @@ _MODULES = {
     "gemma3-1b": "gemma3_1b",
     "phi3.5-moe-42b-a6.6b": "phi3_5_moe_42b",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "rwkv6-1.6b": "rwkv6_1_6b",
     "rwkv6-1.6b-2l-v16k": "rwkv6_1_6b_2l_v16k",
     "qwen2-vl-2b": "qwen2_vl_2b",

@@ -23,13 +23,30 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MLASpec:
-    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3 style)."""
+    """Multi-head latent attention (DeepSeek-V2 / MiniCPM3 style).
+    ``q_lora_rank`` None: the query is one direct projection ``wq``."""
 
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 256
     qk_nope_dim: int = 64
     qk_rope_dim: int = 32
     v_head_dim: int = 64
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN rope scaling as DeepSeek-V2 states it: inverse frequencies
+    blended between base/factor and base over the dims whose wavelength
+    lies between ``beta_fast`` and ``beta_slow`` rotations of the
+    original context; cos/sin scaled by m(mscale) / m(mscale_all_dim)
+    and the softmax by m(mscale_all_dim)^2, m(s) = 0.1 s ln(factor) + 1."""
+
+    factor: float
+    original_max_pos: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -42,6 +59,7 @@ class AttnSpec:
     rope_theta: float = 10_000.0
     window: Optional[int] = None  # sliding-window size; None = global
     mla: Optional[MLASpec] = None
+    rope_scaling: Optional[YarnRope] = None
 
     @property
     def q_dim(self) -> int:
@@ -80,6 +98,15 @@ class MoESpec:
     top_k: int
     d_expert: int
     n_shared: int = 0  # shared (always-on) experts, DeepSeekMoE style
+    norm_topk: bool = True  # renormalise the top-k gates to sum to 1
+    # The experts this layer holds (expert parallelism): ``held`` of them
+    # from ``held_first``; 0 = all. The router always spans n_experts.
+    held_first: int = 0
+    held: int = 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
 
 
 @dataclass(frozen=True)
@@ -131,6 +158,7 @@ class ArchConfig:
     sub_quadratic: bool = False  # eligible for long_500k
     logit_softcap: float = 0.0
     scale_embed: bool = False  # multiply embeddings by sqrt(d_model) (Gemma)
+    moe_aux_coef: float = 0.01  # weight of the MoE balance loss
 
     @property
     def n_layers(self) -> int:
@@ -232,8 +260,9 @@ def _shrink_attn(a: AttnSpec) -> AttnSpec:
     n_kv = max(1, min(a.n_kv_heads, n_heads))
     while n_heads % n_kv:
         n_kv -= 1
-    mla = MLASpec(q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=8,
-                  qk_rope_dim=8, v_head_dim=8) if a.mla else None
+    mla = MLASpec(q_lora_rank=32 if a.mla.q_lora_rank else None,
+                  kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=8,
+                  v_head_dim=8) if a.mla else None
     return dataclasses.replace(
         a, n_heads=n_heads, n_kv_heads=n_kv, head_dim=16 if mla is None else 16,
         window=min(a.window, 32) if a.window else None, mla=mla)
@@ -243,10 +272,11 @@ def _shrink_mlp(m: MLPSpec) -> MLPSpec:
     if m.kind == "moe":
         moe = m.moe
         return dataclasses.replace(
-            m, moe=MoESpec(n_experts=min(moe.n_experts, 4),
-                           top_k=min(moe.top_k, 2),
-                           d_expert=32,
-                           n_shared=min(moe.n_shared, 1)))
+            m, moe=dataclasses.replace(moe, n_experts=min(moe.n_experts, 4),
+                                       top_k=min(moe.top_k, 2),
+                                       d_expert=32,
+                                       n_shared=min(moe.n_shared, 1),
+                                       held_first=0, held=0))
     if m.kind == "dense":
         return dataclasses.replace(m, d_ff=64)
     return m

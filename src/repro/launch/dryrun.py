@@ -99,10 +99,9 @@ def lower_cell(cfg, shape, mesh, rc_base=None, policy=None,
                                policy)
         b_sh = {k: b_sh[k] for k in specs["batch"]}
         step = make_train_step(cfg, rc, opt_cfg)
-        metr_sh = {k: repl for k in ("loss", "gnorm", "xent", "aux")}
         jitted = jax.jit(step,
                          in_shardings=(p_sh, o_sh, b_sh),
-                         out_shardings=(p_sh, o_sh, metr_sh),
+                         out_shardings=(p_sh, o_sh, repl),
                          donate_argnums=(0, 1))
         lowered = jitted.lower(params_shape, opt_shape, specs["batch"])
     elif shape.kind == "prefill":
@@ -226,8 +225,6 @@ def main():
     ap.add_argument("--chunk-q", type=int, default=None)
     ap.add_argument("--mamba-chunk", type=int, default=None)
     ap.add_argument("--rwkv-chunk", type=int, default=None)
-    ap.add_argument("--capacity-factor", type=float, default=None)
-    ap.add_argument("--moe-groups", type=int, default=None)
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--no-mla-absorb", action="store_true")
     ap.add_argument("--fsdp", default=None, choices=[None, "on", "off"])
@@ -238,9 +235,7 @@ def main():
     rc_over = {}
     for k, v in [("attn_impl", args.attn_impl), ("chunk_kv", args.chunk_kv),
                  ("chunk_q", args.chunk_q), ("mamba_chunk", args.mamba_chunk),
-                 ("rwkv_chunk", args.rwkv_chunk),
-                 ("capacity_factor", args.capacity_factor),
-                 ("moe_groups", args.moe_groups)]:
+                 ("rwkv_chunk", args.rwkv_chunk)]:
         if v is not None:
             rc_over[k] = v
     if args.no_remat:

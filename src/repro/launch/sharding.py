@@ -72,7 +72,7 @@ _RULES_2D_OUT_IN = {  # (d_tp, d_out): M, F
 _RULES_VEC_TP = {"bq", "bk", "bv", "conv_b", "dt_bias", "D"}
 _REPLICATED = {
     "scale", "bias", "mix_mu", "mix_x", "mix_w2", "dw2", "w0", "bonus_u",
-    "ln_x_scale", "ln_x_bias", "cmu_k", "cmu_r", "q_a_norm", "kv_a_norm",
+    "ln_x_scale", "ln_x_bias", "cmu_k", "cmu_r",
 }
 
 
@@ -296,10 +296,50 @@ def make_shard_fn(mesh: Mesh, policy: ShardingPolicy = ShardingPolicy(),
     return shard
 
 
+def make_expert_map(mesh: Mesh, policy: ShardingPolicy = ShardingPolicy(),
+                    bsz: int = 0):
+    """Builds RunConfig.expert_map: an MoE layer's routed part, once per
+    device (``shard_map``). Each device routes its own tokens (the batch
+    over the policy's batch axes) over every expert and computes the
+    assignments to its slice of the experts (experts over ``model``
+    where the policy gives the model axis to them, as the parameter
+    rules do); the slices' outputs and counts are summed over ``model``,
+    and the balance loss is averaged over the batch axes."""
+    dp = batch_axes(mesh, policy, bsz) or ()
+    ep = None if policy.mode == "dp_zero1" or "model" in dp else "model"
+
+    def expert_map(run, params, x, first):
+        def body(p, xs):
+            n_local = p["w_gate"].shape[0]
+            idx = jax.lax.axis_index(ep) if ep else 0
+            y, aux, sizes = run(p, xs, first + idx * n_local)
+            if dp:
+                aux = jax.lax.pmean(aux, dp)
+                sizes = jax.lax.psum(sizes, dp)
+            rows, most = sizes.sum(), sizes.max()
+            if ep:
+                y = jax.lax.psum(y, ep)
+                rows, most = jax.lax.psum(rows, ep), jax.lax.pmax(most, ep)
+            return y, aux, rows, most
+
+        experts = P(ep)
+        specs = {"router": P(), "w_gate": experts, "w_up": experts,
+                 "w_down": experts}
+        tokens = P(dp or None)
+        # no replication typing: the passes' cond joins an output that
+        # varies over the mesh with an input that does not
+        return jax.shard_map(body, mesh=mesh, in_specs=(specs, tokens),
+                             out_specs=(tokens, P(), P(), P()),
+                             check_vma=False)(params, x)
+
+    return expert_map
+
+
 def run_config_for(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh,
                    base: Optional[RunConfig] = None,
                    policy: ShardingPolicy = ShardingPolicy()) -> RunConfig:
     import dataclasses
     rc = base or RunConfig()
     return dataclasses.replace(
-        rc, shard=make_shard_fn(mesh, policy, shape.global_batch))
+        rc, shard=make_shard_fn(mesh, policy, shape.global_batch),
+        expert_map=make_expert_map(mesh, policy, shape.global_batch))

@@ -12,7 +12,8 @@ partitioner (it replicates instead of slicing; §Perf iterations 1-3).
 Implementations
 ---------------
 - ``chunked``: scan over KV chunks with running (max, sum, acc) — the
-  flash-attention recurrence in pure jnp (O(S·Ck) peak memory).
+  flash-attention recurrence in pure jnp (O(S·Ck) peak memory, in the
+  backward pass too: each chunk's scores are recomputed there).
 - ``tri``: triangular (q-chunk, kv-chunk) pair iteration, j <= i — skips
   above-diagonal work entirely: half the FLOPs for causal shapes.
 - ``naive``: materializes the full score matrix (perf-iteration baseline).
@@ -24,13 +25,15 @@ Implementations
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import AttnSpec
-from repro.models.layers import apply_rope, normal_init, rms_normalize
+from repro.models.layers import (apply_rope, normal_init, rms_normalize,
+                                 yarn_mscale)
 
 Array = jax.Array
 NEG_INF = -1e30
@@ -61,24 +64,28 @@ def init_attn(key, d_model: int, spec: AttnSpec, dtype,
     if spec.mla is not None:
         m = spec.mla
         qk_dim = m.qk_nope_dim + m.qk_rope_dim
-        return {
-            "q_a": normal_init(ks[0], (d_model, m.q_lora_rank), dtype),
-            "q_a_norm": jnp.ones((m.q_lora_rank,), dtype),
-            "q_b": _pad_cols(normal_init(
-                ks[1], (m.q_lora_rank, spec.n_heads * qk_dim), dtype),
-                extra * qk_dim),
-            "kv_a": normal_init(ks[2], (d_model,
-                                        m.kv_lora_rank + m.qk_rope_dim),
-                                dtype),
-            "kv_a_norm": jnp.ones((m.kv_lora_rank,), dtype),
-            "kv_b": _pad_cols(normal_init(
+        if m.q_lora_rank is None:  # one direct query projection
+            p = {"wq": _pad_cols(normal_init(
+                ks[0], (d_model, spec.n_heads * qk_dim), dtype),
+                extra * qk_dim)}
+        else:
+            p = {"q_a": normal_init(ks[0], (d_model, m.q_lora_rank), dtype),
+                 "q_a_norm": {"scale": jnp.ones((m.q_lora_rank,), dtype)},
+                 "q_b": _pad_cols(normal_init(
+                     ks[1], (m.q_lora_rank, spec.n_heads * qk_dim), dtype),
+                     extra * qk_dim)}
+        return dict(
+            p,
+            kv_a=normal_init(ks[2], (d_model,
+                                     m.kv_lora_rank + m.qk_rope_dim), dtype),
+            kv_a_norm={"scale": jnp.ones((m.kv_lora_rank,), dtype)},
+            kv_b=_pad_cols(normal_init(
                 ks[3], (m.kv_lora_rank,
                         spec.n_heads * (m.qk_nope_dim + m.v_head_dim)),
                 dtype), extra * (m.qk_nope_dim + m.v_head_dim)),
-            "wo": jnp.pad(normal_init(
+            wo=jnp.pad(normal_init(
                 ks[4], (spec.n_heads * m.v_head_dim, d_model), dtype),
-                ((0, extra * m.v_head_dim), (0, 0))),
-        }
+                ((0, extra * m.v_head_dim), (0, 0))))
     kv_extra = 0
     if spec.n_kv_heads == spec.n_heads:  # MHA: pad kv in lockstep
         kv_extra = extra
@@ -162,6 +169,9 @@ def _chunked_attn(q, k, v, q_pos, k_pos, window, scale, chunk_kv):
     v_ = v.reshape(b, h, nc, ck, dv).transpose(2, 0, 1, 3, 4)
     kp = k_pos.reshape(nc, ck)
 
+    # Rematerialized: the backward pass recomputes each chunk's scores
+    # rather than keeping every chunk's (B, H, Sq, ck) probabilities.
+    @partial(jax.checkpoint, prevent_cse=False)
     def body(carry, xs):
         kc, vc, kc_pos = xs
         return _chunk_body(q, kc, vc, kc_pos, q_pos, window, scale,
@@ -331,8 +341,10 @@ def gqa_forward(params: dict, x: Array, spec: AttnSpec, *, positions: Array,
     b, s, _ = x.shape
     q, k, v = _project_qkv(params, x, spec)
     if spec.rope:
-        q = apply_rope(q, positions[None, :], spec.rope_theta)
-        k = apply_rope(k, positions[None, :], spec.rope_theta)
+        q = apply_rope(q, positions[None, :], spec.rope_theta,
+                       spec.rope_scaling)
+        k = apply_rope(k, positions[None, :], spec.rope_theta,
+                       spec.rope_scaling)
     new_cache = None
     if cache is not None:  # prefill: write into the cache at [0, s)
         new_cache = {
@@ -358,8 +370,8 @@ def gqa_decode(params: dict, x: Array, spec: AttnSpec, *, pos: Array,
     q, k, v = _project_qkv(params, x, spec)
     posv = pos[None, None]
     if spec.rope:
-        q = apply_rope(q, posv, spec.rope_theta)
-        k = apply_rope(k, posv, spec.rope_theta)
+        q = apply_rope(q, posv, spec.rope_theta, spec.rope_scaling)
+        k = apply_rope(k, posv, spec.rope_theta, spec.rope_scaling)
     k_cache = jax.lax.dynamic_update_slice_in_dim(
         cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
     v_cache = jax.lax.dynamic_update_slice_in_dim(
@@ -380,8 +392,12 @@ def _mla_q(params, x, spec):
     m = spec.mla
     b, s, _ = x.shape
     qk_dim = m.qk_nope_dim + m.qk_rope_dim
-    q_c = rms_normalize(x @ params["q_a"]) * params["q_a_norm"]
-    q = (q_c @ params["q_b"]).reshape(b, s, -1, qk_dim)
+    if m.q_lora_rank is None:
+        q = x @ params["wq"]
+    else:
+        q_c = rms_normalize(x @ params["q_a"]) * params["q_a_norm"]["scale"]
+        q = q_c @ params["q_b"]
+    q = q.reshape(b, s, -1, qk_dim)
     return jnp.split(q, [m.qk_nope_dim], axis=-1)  # q_nope, q_rope
 
 
@@ -389,9 +405,20 @@ def _mla_kv_compress(params, x, spec, positions):
     m = spec.mla
     kv = x @ params["kv_a"]
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
-    c_kv = rms_normalize(c_kv) * params["kv_a_norm"]
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, spec.rope_theta)
+    c_kv = rms_normalize(c_kv) * params["kv_a_norm"]["scale"]
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, spec.rope_theta,
+                        spec.rope_scaling)
     return c_kv, k_rope  # (B,S,r), (B,S,1,rope)
+
+
+def _mla_scale(spec) -> float:
+    """1/sqrt(qk head dim), times YaRN's m(mscale_all_dim)^2."""
+    m = spec.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    y = spec.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def _mla_expand(params, c_kv, spec):
@@ -409,14 +436,15 @@ def mla_forward(params: dict, x: Array, spec: AttnSpec, *, positions: Array,
     m = spec.mla
     b, s, _ = x.shape
     q_nope, q_rope = _mla_q(params, x, spec)
-    q_rope = apply_rope(q_rope, positions[None, :], spec.rope_theta)
+    q_rope = apply_rope(q_rope, positions[None, :], spec.rope_theta,
+                        spec.rope_scaling)
     c_kv, k_rope = _mla_kv_compress(params, x, spec, positions[None, :])
     k_nope, v = _mla_expand(params, c_kv, spec)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, (*k_nope.shape[:3],
                                            m.qk_rope_dim))], axis=-1)
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(spec)
     new_cache = None
     if cache is not None:  # cache the *compressed* kv (the MLA win)
         new_cache = {
@@ -447,7 +475,8 @@ def mla_decode(params: dict, x: Array, spec: AttnSpec, *, pos: Array,
     b, s, _ = x.shape
     cur_pos = jnp.broadcast_to(pos, (b,))
     q_nope, q_rope = _mla_q(params, x, spec)
-    q_rope = apply_rope(q_rope, pos[None, None], spec.rope_theta)
+    q_rope = apply_rope(q_rope, pos[None, None], spec.rope_theta,
+                        spec.rope_scaling)
     c_kv_new, k_rope_new = _mla_kv_compress(params, x, spec, pos[None, None])
     c_kv = jax.lax.dynamic_update_slice_in_dim(
         cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), pos, axis=1)
@@ -455,7 +484,7 @@ def mla_decode(params: dict, x: Array, spec: AttnSpec, *, pos: Array,
         cache["k_rope"], k_rope_new[:, :, 0].astype(cache["k_rope"].dtype),
         pos, axis=1)
     new_cache = {"c_kv": c_kv, "k_rope": k_rope}
-    scale = 1.0 / math.sqrt(m.qk_nope_dim + m.qk_rope_dim)
+    scale = _mla_scale(spec)
     kpos = jnp.arange(c_kv.shape[1])
     mask = (kpos[None, :] <= cur_pos[:, None])[:, None, None, :]
 

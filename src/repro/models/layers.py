@@ -66,17 +66,44 @@ def act_fn(name: str):
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(dim: int, theta: float) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., S, H, D) with rotary over D; positions: (..., S)."""
+def rope_freqs(dim: int, theta: float, scaling=None) -> Array:
+    """Inverse frequencies; ``scaling`` (a YarnRope) blends base/factor
+    into the low frequencies over YaRN's correction range."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return freqs
+
+    def corr(rotations):  # the dim whose wavelength fits ``rotations``
+        return dim * math.log(scaling.original_max_pos
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr(scaling.beta_slow)), dim - 1)
+    high = high if high > low else low + 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: Array, positions: Array, theta: float,
+               scaling=None) -> Array:
+    """x: (..., S, H, D) with rotary over D (halves rotated as pairs);
+    positions: (..., S). ``scaling``: a YarnRope, or None."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # (d/2,)
+    freqs = rope_freqs(d, theta, scaling)  # (d/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, d/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, d/2)
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        m = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -47,8 +47,6 @@ class RunConfig:
     chunk_kv: int = 1024
     mamba_chunk: int = 128
     rwkv_chunk: int = 64
-    capacity_factor: float = 1.25
-    moe_groups: int = 0  # 0 = auto policy (per-seq train, 16-token decode)
     remat: bool = True
     loss_chunk: int = 1024  # seq-chunked vocab xent (rematerialized)
     head_pad: int = 1  # pad head counts to this multiple (TP divisibility)
@@ -58,6 +56,9 @@ class RunConfig:
     scan_stages: bool = True  # False unrolls layers (perf/compile comparison)
     # Injected by the launch layer: shard(x, partition_tuple) -> x
     shard: Optional[Callable] = None
+    # Injected by the launch layer: expert_map(run, params, x, first)
+    # runs an MoE layer's routed experts once per device (moe.py)
+    expert_map: Optional[Callable] = None
 
 
 # ===========================================================================
@@ -184,13 +185,15 @@ def _apply_mixer(cfg, spec: LayerSpec, rc: RunConfig, params, x, *, mode,
     if spec.kind == "attn":
         a = spec.attn
         if a.mla is not None:
-            if mode == "decode":
-                return attn_mod.mla_decode(params, x, a, pos=pos, cache=cache,
-                                           absorb=rc.mla_absorb)
-            return attn_mod.mla_forward(params, x, a, positions=positions,
-                                        impl=rc.attn_impl, chunk_q=rc.chunk_q,
-                                        chunk_kv=rc.chunk_kv, cache=cache,
-                                        shard=rc.shard)
+            with jax.named_scope("mla"):
+                if mode == "decode":
+                    return attn_mod.mla_decode(params, x, a, pos=pos,
+                                               cache=cache,
+                                               absorb=rc.mla_absorb)
+                return attn_mod.mla_forward(
+                    params, x, a, positions=positions, impl=rc.attn_impl,
+                    chunk_q=rc.chunk_q, chunk_kv=rc.chunk_kv, cache=cache,
+                    shard=rc.shard)
         if mode == "decode":
             return attn_mod.gqa_decode(params, x, a, pos=pos, cache=cache)
         return attn_mod.gqa_forward(params, x, a, positions=positions,
@@ -210,24 +213,38 @@ def _apply_mixer(cfg, spec: LayerSpec, rc: RunConfig, params, x, *, mode,
     raise ValueError(spec.kind)
 
 
+def _no_stats() -> dict:
+    """A layer's training counters: the MoE balance loss, the expert rows
+    computed (summed over layers) and the most rows of one expert (the
+    largest over layers)."""
+    return {"aux": jnp.zeros((), jnp.float32),
+            "moe_rows": jnp.zeros((), jnp.int32),
+            "moe_max_rows": jnp.zeros((), jnp.int32)}
+
+
+def _add_stats(a: dict, b: dict) -> dict:
+    return {"aux": a["aux"] + b["aux"],
+            "moe_rows": a["moe_rows"] + b["moe_rows"],
+            "moe_max_rows": jnp.maximum(a["moe_max_rows"], b["moe_max_rows"])}
+
+
 def _apply_block(cfg, spec: LayerSpec, rc, params, x, *, mode, positions,
-                 pos, cache, n_groups):
+                 pos, cache):
     new_cache = {} if cache is not None else None
     h = apply_norm(cfg.norm, params["ln1"], x, cfg.norm_eps)
     mix_out, mix_cache = _apply_mixer(cfg, spec, rc, params["mixer"], h,
                                       mode=mode, positions=positions, pos=pos,
                                       cache=cache)
     x = x + mix_out
-    aux = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     h = apply_norm(cfg.norm, params["ln2"], x, cfg.norm_eps)
     if spec.mlp.kind == "dense":
         x = x + apply_mlp(params["mlp"], h, spec.mlp.act)
         mlp_cache = None
     elif spec.mlp.kind == "moe":
-        y, aux = moe_mod.apply_moe(params["mlp"], h, spec.mlp.moe,
-                                   spec.mlp.act, n_groups=n_groups,
-                                   capacity_factor=rc.capacity_factor,
-                                   shard=rc.shard)
+        y, stats = moe_mod.apply_held_moe(params["mlp"], h, spec.mlp.moe,
+                                          spec.mlp.act,
+                                          expert_map=rc.expert_map)
         x = x + y
         mlp_cache = None
     elif spec.kind == "rwkv":
@@ -242,68 +259,64 @@ def _apply_block(cfg, spec: LayerSpec, rc, params, x, *, mode, positions,
             new_cache.update(mlp_cache)
     if rc.shard is not None:
         x = rc.shard(x, ("data", None, None))
-    return x, new_cache, aux
+    return x, new_cache, stats
 
 
 def _apply_superblock(cfg, stage: Stage, rc, params, x, *, mode, positions,
-                      pos, cache, n_groups):
+                      pos, cache):
     new_cache = {} if cache is not None else None
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     for i, spec in enumerate(stage.block):
         li = f"L{i}"
-        x, c_new, aux = _apply_block(
+        x, c_new, st = _apply_block(
             cfg, spec, rc, params[li], x, mode=mode, positions=positions,
-            pos=pos, cache=None if cache is None else cache[li],
-            n_groups=n_groups)
+            pos=pos, cache=None if cache is None else cache[li])
         if cache is not None:
             new_cache[li] = c_new
-        aux_total = aux_total + aux
-    return x, new_cache, aux_total
+        stats = _add_stats(stats, st)
+    return x, new_cache, stats
 
 
 def _apply_stage(cfg, stage: Stage, rc, params, x, *, mode, positions, pos,
-                 cache, n_groups):
+                 cache):
     if stage.repeat == 1 or not rc.scan_stages:
         if stage.repeat == 1:
             return _apply_superblock(cfg, stage, rc, params, x, mode=mode,
                                      positions=positions, pos=pos,
-                                     cache=cache, n_groups=n_groups)
+                                     cache=cache)
         # unrolled path (scan_stages=False): index the stacked params
-        aux_t = jnp.zeros((), jnp.float32)
+        stats = _no_stats()
         new_cache = {} if cache is not None else None
         caches_out = []
         for r in range(stage.repeat):
             p_r = jax.tree.map(lambda t: t[r], params)
             c_r = None if cache is None else jax.tree.map(lambda t: t[r],
                                                           cache)
-            x, c_new, aux = _apply_superblock(cfg, stage, rc, p_r, x,
-                                              mode=mode, positions=positions,
-                                              pos=pos, cache=c_r,
-                                              n_groups=n_groups)
+            x, c_new, st = _apply_superblock(cfg, stage, rc, p_r, x,
+                                             mode=mode, positions=positions,
+                                             pos=pos, cache=c_r)
             caches_out.append(c_new)
-            aux_t = aux_t + aux
+            stats = _add_stats(stats, st)
         if cache is not None:
             new_cache = jax.tree.map(lambda *ts: jnp.stack(ts), *caches_out)
-        return x, new_cache, aux_t
+        return x, new_cache, stats
 
     def body(carry, xs):
-        x_, aux_ = carry
+        x_, stats_ = carry
         if cache is None:
             p_r, c_r = xs, None
         else:
             p_r, c_r = xs
-        x_, c_new, aux = _apply_superblock(cfg, stage, rc, p_r, x_,
-                                           mode=mode, positions=positions,
-                                           pos=pos, cache=c_r,
-                                           n_groups=n_groups)
-        return (x_, aux_ + aux), c_new
+        x_, c_new, st = _apply_superblock(cfg, stage, rc, p_r, x_,
+                                          mode=mode, positions=positions,
+                                          pos=pos, cache=c_r)
+        return (x_, _add_stats(stats_, st)), c_new
 
     if rc.remat and mode == "train":
         body = jax.checkpoint(body, prevent_cse=False)
     xs = params if cache is None else (params, cache)
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       xs)
-    return x, new_cache, aux
+    (x, stats), new_cache = jax.lax.scan(body, (x, _no_stats()), xs)
+    return x, new_cache, stats
 
 
 def _embed(cfg: ArchConfig, params, tokens, frontend, positions):
@@ -333,7 +346,7 @@ def _logits(cfg: ArchConfig, params, x, rc):
 
 def forward(cfg: ArchConfig, rc: RunConfig, params, tokens, *,
             frontend=None, mode: str = "train", caches=None, pos=None):
-    """tokens: (B,S) [decode: (B,1)]. Returns (hidden, new_caches, aux) —
+    """tokens: (B,S) [decode: (B,1)]. Returns (hidden, new_caches, stats) —
     hidden is the final-norm output; entry points project to logits only
     where needed (last position for prefill; seq-chunked for the loss)."""
     b, s = tokens.shape
@@ -346,22 +359,20 @@ def forward(cfg: ArchConfig, rc: RunConfig, params, tokens, *,
         total = s + (frontend.shape[1] if frontend is not None else 0)
         positions = jnp.arange(total)
         x = _embed(cfg, params, tokens, frontend, positions[None])
-    n_groups = rc.moe_groups or moe_mod.default_groups(
-        b, x.shape[1], mode)
     if rc.shard is not None:
         x = rc.shard(x, ("data", None, None))
     new_caches = [] if caches is not None else None
-    aux_total = jnp.zeros((), jnp.float32)
+    stats = _no_stats()
     for i, stage in enumerate(cfg.stages):
-        x, c_new, aux = _apply_stage(
+        x, c_new, st = _apply_stage(
             cfg, stage, rc, params["stages"][i], x, mode=mode,
             positions=positions, pos=pos,
-            cache=None if caches is None else caches[i], n_groups=n_groups)
-        aux_total = aux_total + aux
+            cache=None if caches is None else caches[i])
+        stats = _add_stats(stats, st)
         if caches is not None:
             new_caches.append(c_new)
     x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
-    return x, new_caches, aux_total
+    return x, new_caches, stats
 
 
 # ===========================================================================
@@ -407,19 +418,19 @@ def _chunked_xent(cfg: ArchConfig, rc: RunConfig, params, x, labels):
     return sum_e, sum_m
 
 
-def loss_fn(cfg: ArchConfig, rc: RunConfig, params, batch,
-            aux_coef: float = 0.01):
+def loss_fn(cfg: ArchConfig, rc: RunConfig, params, batch):
     """batch: tokens (B,S) int32, labels (B,S) int32 (-1 = masked),
-    optional frontend_embeds (B,Nf,d)."""
+    optional frontend_embeds (B,Nf,d). The MoE balance loss is weighted
+    by the model's ``moe_aux_coef``."""
     frontend = batch.get("frontend_embeds")
-    hidden, _, aux = forward(cfg, rc, params, batch["tokens"],
+    hidden, _, stats = forward(cfg, rc, params, batch["tokens"],
                              frontend=frontend, mode="train")
     nf = frontend.shape[1] if frontend is not None else 0
     hidden = hidden[:, nf:]  # token positions only
     sum_e, sum_m = _chunked_xent(cfg, rc, params, hidden, batch["labels"])
     xent = sum_e / jnp.maximum(sum_m, 1.0)
-    loss = xent + aux_coef * aux
-    return loss, {"xent": xent, "aux": aux}
+    loss = xent + cfg.moe_aux_coef * stats["aux"]
+    return loss, {"xent": xent, **stats}
 
 
 def prefill(cfg: ArchConfig, rc: RunConfig, params, tokens, caches, *,
@@ -475,7 +486,9 @@ def count_params(cfg: ArchConfig, rc: RunConfig = RunConfig()) -> int:
 
 
 def count_active_params(cfg: ArchConfig) -> int:
-    """Params touched per token: total minus inactive routed experts."""
+    """Params touched per token: total minus inactive routed experts (of
+    a layer that holds some experts, those a token leaves, on average,
+    when its top-k spreads evenly over all)."""
     total = count_params(cfg)
     inactive = 0
     for spec in cfg.layer_specs():
@@ -483,5 +496,6 @@ def count_active_params(cfg: ArchConfig) -> int:
             m = spec.mlp.moe
             gated = 3  # swiglu/geglu experts have 3 matrices
             per_expert = gated * cfg.d_model * m.d_expert
-            inactive += (m.n_experts - m.top_k) * per_expert
+            inactive += m.n_held * (m.n_experts - m.top_k) * per_expert \
+                // m.n_experts
     return total - inactive

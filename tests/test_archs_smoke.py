@@ -62,6 +62,7 @@ def test_param_counts_match_published():
         "stablelm-12b": 12.1, "minicpm3-4b": 4.1, "gemma3-1b": 1.0,
         "phi3.5-moe-42b-a6.6b": 41.9, "deepseek-moe-16b": 16.4,
         "rwkv6-1.6b": 1.6, "qwen2-vl-2b": 1.5, "musicgen-large": 2.4,
+        "deepseek-v2-lite": 15.7,
         "rwkv6-1.6b-2l-v16k": 0.178,  # configs/rwkv6_1_6b_2l_v16k.py
     }
     for arch, bn in expect.items():
@@ -76,3 +77,24 @@ def test_long_500k_applicability_flags():
             if shape_applicable(get_config(a), ls)}
     assert runs == {"jamba-1.5-large-398b", "rwkv6-1.6b",
                     "rwkv6-1.6b-2l-v16k", "gemma3-1b"}
+
+
+def test_train_step_counts_expert_rows(small_rc):
+    """The train step's metrics carry the MoE counters: every (token,
+    expert) assignment of every MoE layer computed (all experts held in
+    the reduced model, none dropped), and the most rows of one expert."""
+    from repro.launch.train import make_train_step
+    from repro.optim.adamw import AdamWConfig, adamw_init
+    cfg = reduced(get_config("deepseek-v2-lite"))
+    moe = [s.mlp.moe for s in cfg.layer_specs() if s.mlp.kind == "moe"]
+    params = init_params(cfg, jax.random.key(0), small_rc)
+    b, s = 2, 64
+    tokens = jax.random.randint(jax.random.key(1), (b, s), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}
+    step = make_train_step(cfg, small_rc, AdamWConfig())
+    _, _, metrics = step(params, adamw_init(params), batch)
+    assert int(metrics["moe_rows"]) == b * s * sum(m.top_k for m in moe)
+    top_k, n_experts = moe[0].top_k, moe[0].n_experts
+    assert b * s * top_k / n_experts <= int(metrics["moe_max_rows"]) <= b * s
+    assert float(metrics["aux"]) > 0

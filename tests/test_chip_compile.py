@@ -10,7 +10,10 @@ times the leaf in temporary memory.
 And rwkv6-1.6b's time mix, forward and backward, at the benchmark's batch
 (16 x 2048 tokens, 32 heads of 64): its memory plan against that of the
 wkv scan's earlier form, which built the (B, L, L, H, dk) pairwise decays
-of each 64-token chunk."""
+of each 64-token chunk.
+
+And the DeepSeek-V2-Lite cell's whole train step, which the compiler
+refuses if it does not fit the chip's memory."""
 import os
 from functools import partial
 
@@ -128,3 +131,41 @@ def test_rwkv_time_mix_plan_within_pairwise_form(one_chip, no_compile_cache,
     sub_blocks = temp_bytes()
     monkeypatch.setattr(S, "_wkv", _pairwise_wkv)
     assert sub_blocks < temp_bytes()
+
+
+def test_dsv2lite_cell_step_fits_a_v5e(one_chip, no_compile_cache):
+    """The ``dsv2lite-5l.steady`` cell's train step (DeepSeek-V2-Lite, 1
+    dense + 4 MoE layers holding 8 of 64 experts, vocabulary 12,800, f32
+    state and AdamW moments) at its traffic's 4 x 4096 tokens. The TPU
+    compiler refuses a program whose buffers, the state among them,
+    exceed the chip's memory, so compiling is the check; the state is the
+    6.42 GB the configuration states."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.chip import spec
+    from repro.launch.train import make_train_step
+    from repro.models.transformer import RunConfig, init_params
+    from repro.optim.adamw import AdamWConfig, adamw_init
+    bench = spec.Bench()
+    cell = bench.workload("dsv2lite-5l.steady")
+    cfg = bench.config(cell["config"])
+    traffic = bench.traffic(cell["traffic"])
+    arch = spec.arch(cfg["arch"]).arch_config(cfg)
+    rc = RunConfig(param_dtype=jnp.float32, cache_dtype=jnp.float32)
+    params = jax.eval_shape(lambda: init_params(arch, jax.random.key(0), rc))
+    on_chip = partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    batch = {k: jax.ShapeDtypeStruct((traffic["batch"], traffic["seq"]),
+                                     jnp.int32, sharding=one_chip)
+             for k in ("tokens", "labels")}
+    step = make_train_step(arch, rc, AdamWConfig(**cfg["optimizer"]))
+    compiled = step.lower(on_chip(params), on_chip(jax.eval_shape(
+        adamw_init, params)), batch).compile()
+    mem = compiled.memory_analysis()
+    n_params = sum(x.size for x in jax.tree.leaves(params))
+    assert n_params == 535_060_992
+    assert mem.argument_size_in_bytes - 12 * n_params < 1 << 20
+    assert "ragged" in compiled.as_text()

@@ -6,7 +6,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import AttnSpec, MLASpec, MambaSpec, MoESpec, RWKVSpec
+from repro.configs.base import (AttnSpec, MLASpec, MambaSpec, MoESpec,
+                                RWKVSpec, YarnRope)
 from repro.models import attention as A
 from repro.models import moe as M
 from repro.models import ssm as S
@@ -61,9 +62,13 @@ def test_gqa_prefill_decode_consistency():
 
 
 def test_mla_absorb_equals_expand():
-    spec = AttnSpec(n_heads=4, n_kv_heads=4, head_dim=16,
-                    mla=MLASpec(q_lora_rank=24, kv_lora_rank=16,
-                                qk_nope_dim=8, qk_rope_dim=8, v_head_dim=8))
+    _absorb_equals_expand(AttnSpec(
+        n_heads=4, n_kv_heads=4, head_dim=16,
+        mla=MLASpec(q_lora_rank=24, kv_lora_rank=16, qk_nope_dim=8,
+                    qk_rope_dim=8, v_head_dim=8)))
+
+
+def _absorb_equals_expand(spec):
     d_model = 32
     params = A.init_attn(jax.random.key(1), d_model, spec, jnp.float32)
     s = 16
@@ -259,26 +264,43 @@ def test_moe_routing_sanity():
     params = M.init_moe(jax.random.key(5), d_model, spec, "swiglu",
                         jnp.float32)
     x = _mk((2, 16, d_model))
-    y, aux = M.apply_moe(params, x, spec, "swiglu", n_groups=2,
-                         capacity_factor=2.0)
+    y, stats = M.apply_held_moe(params, x, spec, "swiglu")
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
-    assert 0.5 < float(aux) < 4.0  # balanced-ish routing near init
+    assert 0.5 < float(stats["aux"]) < 4.0  # balanced-ish routing near init
 
-    # generous capacity: moe output must not depend on group split
-    y1, _ = M.apply_moe(params, x, spec, "swiglu", n_groups=1,
-                        capacity_factor=4.0)
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y1), atol=1e-4,
-                               rtol=1e-4)
+    # dropless: a token's output does not depend on the batch it came in
+    y1, _ = M.apply_held_moe(params, x[1:], spec, "swiglu")
+    np.testing.assert_allclose(np.asarray(y[1:]), np.asarray(y1), atol=1e-5,
+                               rtol=1e-5)
 
 
-def test_moe_capacity_drops_overflow():
-    spec = MoESpec(n_experts=2, top_k=1, d_expert=8)
+def test_moe_skewed_router_drops_nothing():
+    """Every token routed to one expert: all of them are computed, as the
+    expert's own product (no capacity, no drop)."""
+    spec = MoESpec(n_experts=2, top_k=1, d_expert=8, norm_topk=False)
     d_model = 4
     params = M.init_moe(jax.random.key(6), d_model, spec, "swiglu",
                         jnp.float32)
-    # tiny capacity factor forces drops; output must stay finite
-    x = _mk((1, 32, d_model))
-    y, _ = M.apply_moe(params, x, spec, "swiglu", n_groups=1,
-                       capacity_factor=0.1)
-    assert np.isfinite(np.asarray(y)).all()
+    x = jnp.abs(_mk((1, 32, d_model))) + 0.1
+    params["router"] = jnp.stack([jnp.full((d_model,), 5.0),
+                                  jnp.full((d_model,), -5.0)], axis=1)
+    y, stats = M.apply_held_moe(params, x, spec, "swiglu")
+    p0 = jax.nn.softmax(x @ params["router"], axis=-1)[..., :1]
+    h = jax.nn.silu(x @ params["w_gate"][0]) * (x @ params["w_up"][0])
+    want = p0 * (h @ params["w_down"][0])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5,
+                               rtol=1e-4)
+    assert int(stats["moe_rows"]) == 32
+    assert int(stats["moe_max_rows"]) == 32
+
+
+def test_mla_absorb_equals_expand_direct_query_yarn():
+    """No query low-rank (one ``wq``, DeepSeek-V2-Lite's form) and YaRN
+    rope with its softmax scale."""
+    _absorb_equals_expand(AttnSpec(
+        n_heads=4, n_kv_heads=4, head_dim=16,
+        mla=MLASpec(q_lora_rank=None, kv_lora_rank=16, qk_nope_dim=8,
+                    qk_rope_dim=8, v_head_dim=8),
+        rope_scaling=YarnRope(factor=40.0, original_max_pos=4096,
+                              mscale=0.707, mscale_all_dim=0.707)))
