@@ -31,7 +31,7 @@ from typing import List, Optional
 from repro.core.log import _HDR, _WRITE_BUF  # wire header / buffer size
 from repro.core.extents import apply_range_write
 from repro.core.integrity import full_sum, prefix_sums
-from repro.core.log import (Entry, affected_paths, decode_stream,
+from repro.core.log import (MAGIC, Entry, affected_paths, decode_stream,
                             renames_touch)
 from repro.core.transport import next_rkey, with_retries
 
@@ -52,6 +52,26 @@ def _apply_to_table(table: dict, e: Entry) -> None:
         table[e.path] = None  # tombstone first: self-rename safe
         if val is not None:
             table[e.data.decode()] = val
+
+
+def _held_prefix(data, tail: int) -> int:
+    """Byte length of the whole entries of ``data`` when none has a
+    seqno above ``tail``, else -1 — a walk over the headers alone
+    (magic and lengths: no CRC, no copy) that stops where
+    ``decode_stream`` stops at a torn record. At 0 or more, decoding
+    the delivery would keep no entry."""
+    off, n = 0, len(data)
+    while off + _HDR.size <= n:
+        magic, seqno, _, plen, dlen, _, _ = _HDR.unpack_from(data, off)
+        if magic != MAGIC:
+            break
+        end = off + _HDR.size + plen + dlen
+        if end > n:
+            break
+        if seqno > tail:
+            return -1
+        off = end
+    return off
 
 
 class ReplicaSlot:
@@ -91,6 +111,11 @@ class ReplicaSlot:
         self.region_id: Optional[str] = None  # set at registration
         self.acked_seqno = 0
         self.digested_seqno = 0
+        # deliveries (and their bytes) every entry of which the slot
+        # already held, skipped whole before any decode: the chain's
+        # continue RPC repeating the one-sided write it follows
+        self.held_deliveries = 0
+        self.held_bytes = 0
         # serializes appends (chain writes) against truncation (digest
         # fan-out runs on the writer's background worker): both reshape
         # the entry/offset lists and the slot file
@@ -218,7 +243,7 @@ class ReplicaSlot:
 
     # transport sink interface -------------------------------------------------
     def write(self, offset: Optional[int], data: bytes,
-              sync: bool = True) -> None:
+              sync: bool = True) -> int:
         """One-sided append (RDMA WRITE). Persist + decode new entries.
 
         Idempotent by seqno: entries at or below the slot's tail (or its
@@ -226,19 +251,28 @@ class ReplicaSlot:
         write — a retried chain step after a dropped ack, or an injected
         duplicate delivery — never double-applies. Entries in one stream
         have strictly increasing seqnos, so the survivors are a byte
-        suffix of ``data``.
+        suffix of ``data``. A delivery the slot already holds whole —
+        the chain's continue RPC after its one-sided write — is found by
+        its headers alone and skipped before any decode; returns the
+        bytes so skipped (0 when the delivery was decoded).
 
         ``sync=False`` flushes to the OS but skips the per-file fsync:
         the group-commit sink calls it once per batch member and makes
         the whole batch durable with ONE journal fsync instead (see
         ``groupcommit.GroupSlotSink``)."""
         with self._lock:
-            entries = decode_stream(data)
             tail = (self.entries[-1].seqno if self.entries
                     else self.digested_seqno)
+            held = _held_prefix(data, tail)
+            if held >= 0:
+                if held:
+                    self.held_deliveries += 1
+                    self.held_bytes += held
+                return held
+            entries = decode_stream(data)
             keep = [e for e in entries if e.seqno > tail]
             if not keep:
-                return
+                return 0
             if len(keep) != len(entries):
                 skip = sum(e.nbytes for e in entries[:len(entries)
                                                     - len(keep)])
@@ -250,6 +284,7 @@ class ReplicaSlot:
             start = len(self._buf)
             self._buf += data
             self._ingest(keep, start)
+            return 0
 
     def read(self, offset: int, size: int) -> bytes:
         # locked: a concurrent truncation reshapes _buf, and a one-sided

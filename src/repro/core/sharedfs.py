@@ -328,9 +328,8 @@ class SharedFS:
         likewise skipped rather than replayed over newer state."""
         slot = self.slot_for(proc_id)
         with self.transport.tracer.span("repl.hop", node=self.node_id,
-                                        nbytes=len(data)):
-            if data:
-                slot.write(None, data)
+                                        nbytes=len(data)) as sp:
+            sp.count(held_bytes=slot.write(None, data))
             if rest:
                 head, tail = rest[0], rest[1:]
                 # a middle replica dying right here leaves the prefix
